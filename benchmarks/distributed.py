@@ -15,9 +15,11 @@ over 1..8 host-platform devices and records per-step timing to
 Any halo/migration/box overflow flag fails the run (exit 1) — the §4.2
 never-silent-loss contract extends to benchmarks.
 
-Must run as its own process (forces the device count before importing jax):
+Must run as its own process: on the CPU (``JAX_PLATFORMS=cpu``) it forces
+eight host devices before importing jax, elsewhere it uses the devices JAX
+finds, and a shard count above the device count fails the run:
 
-    PYTHONPATH=src:. python -m benchmarks.distributed
+    JAX_PLATFORMS=cpu PYTHONPATH=src:. python -m benchmarks.distributed
 
 Env overrides for CI smoke: DIST_BENCH_AGENTS_PER_SHARD, DIST_BENCH_SHARDS
 (comma-separated), DIST_BENCH_STEPS.
@@ -27,7 +29,9 @@ from __future__ import annotations
 
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import time  # noqa: E402
 
@@ -95,8 +99,13 @@ def _run_case(n_shards: int, n_total: int) -> dict:
 
 def run() -> None:
     import jax
-    n_dev = len(jax.devices())
-    shard_counts = [s for s in SHARD_COUNTS if s <= n_dev]
+    devices = jax.devices()
+    n_dev = len(devices)
+    if max(SHARD_COUNTS) > n_dev:
+        raise SystemExit(f"shard counts {SHARD_COUNTS} need "
+                         f"{max(SHARD_COUNTS)} devices; found {n_dev} "
+                         f"{devices[0].platform} device(s)")
+    shard_counts = list(SHARD_COUNTS)
     record = {"device_count": n_dev, "backend": jax.default_backend(),
               "agents_per_shard": AGENTS_PER_SHARD,
               "weak": [], "strong": []}

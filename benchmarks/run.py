@@ -19,8 +19,12 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
     from . import (allocator, breakdown, capacity, cellsort, ensemble,
                    neighbor, optimizations, scaling, sorting)
+
+    compile_cache.enable()
 
     modules = [("fig5_breakdown", breakdown), ("fig6_scaling", scaling),
                ("fig7_cellsort", cellsort), ("fig9_optimizations", optimizations),

@@ -76,10 +76,10 @@ def main_distributed(n_shards: int = 4):
     x-slabs with in-loop rebalance; RandomWalk draws differ per shard, so
     curves are statistically (not bitwise) equal to the single-device run."""
     import jax
-    if len(jax.devices()) < n_shards:
-        raise SystemExit(
-            f"need {n_shards} devices — run with XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n_shards}")
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        raise SystemExit(f"need {n_shards} devices; found {len(devices)} "
+                         f"{devices[0].platform} device(s)")
     rng = np.random.default_rng(1)
     pos, types = initial_population(rng)
     local_capacity = 2 * N_AGENTS // n_shards

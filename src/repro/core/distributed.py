@@ -205,17 +205,8 @@ def _ppermute_tree(tree, axis: str, perm):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (new API, else experimental)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class _ShardedDiffusionOps(diff_mod.DiffusionOps):
@@ -496,8 +487,9 @@ class DistributedSimulation:
             devices = jax.devices()
             if len(devices) < dcfg.n_shards:
                 raise ValueError(
-                    f"n_shards={dcfg.n_shards} > {len(devices)} devices "
-                    f"(set XLA_FLAGS=--xla_force_host_platform_device_count)")
+                    f"n_shards={dcfg.n_shards} needs {dcfg.n_shards} devices;"
+                    f" found {len(devices)} {devices[0].platform} "
+                    f"device(s)")
             mesh = jax.sharding.Mesh(np.array(devices[:dcfg.n_shards]),
                                      (axis,))
         self.mesh = mesh

@@ -40,7 +40,6 @@ Alternative environments (paper Fig 11 comparison, DESIGN.md §11.5):
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import warnings
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
@@ -157,17 +156,11 @@ def _np_stable_argsort(keys: np.ndarray) -> np.ndarray:
     return np.argsort(np.asarray(keys), kind="stable").astype(np.int32)
 
 
-# jax ≥ 0.5 replaces pure_callback's ``vectorized`` kwarg with ``vmap_method``
-_CALLBACK_KW = (
-    {"vmap_method": "sequential"}
-    if "vmap_method" in inspect.signature(jax.pure_callback).parameters
-    else {"vectorized": False})
-
-
 def _counting_sort_host(keys: jnp.ndarray) -> jnp.ndarray:
     return jax.pure_callback(
         _np_stable_argsort,
-        jax.ShapeDtypeStruct(keys.shape, jnp.int32), keys, **_CALLBACK_KW)
+        jax.ShapeDtypeStruct(keys.shape, jnp.int32), keys,
+        vmap_method="sequential")
 
 
 def _radix_pass(vals: jnp.ndarray, order: jnp.ndarray, shift: int
@@ -471,14 +464,6 @@ def grow_grid_state(grid: GridState, new_capacity: int) -> GridState:
         max_run_count=grid.max_run_count.astype(cdt))
 
 
-def _pcast_varying(v: jnp.ndarray, axes: Tuple[str, ...]) -> jnp.ndarray:
-    """jax.lax.pcast(..., to="varying") with a no-op fallback for jax < 0.6
-    (older shard_map has no varying-axis tracking to satisfy)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(v, axes, to="varying")
-    return v
-
-
 def table_count_dtype(capacity: int) -> jnp.dtype:
     """Dtype of per-box/per-bucket occupancy tables, capacity-parameterized.
 
@@ -634,8 +619,8 @@ def build_pairlist(spec: GridSpec, grid: GridState, position: jnp.ndarray,
 
     carry0 = (jnp.zeros((c, p), jnp.int32), jnp.zeros((c, 10), jnp.int32),
               jnp.zeros((c,), jnp.int32), jnp.zeros((), jnp.int32))
-    if pvary_axes:   # under shard_map: mark the carry varying on those axes
-        carry0 = tuple(_pcast_varying(v, pvary_axes) for v in carry0)
+    # under shard_map: mark the carry varying on those axes (no-op for ())
+    carry0 = jax.lax.pcast(carry0, pvary_axes, to="varying")
 
     def body(i, carry):
         idx_t, off_t, cnt_t, demand = carry
@@ -825,8 +810,8 @@ def resident_apply(spec: GridSpec,
     r_cap = spec.run_capacity
     blk_idx, n_blk = compaction.active_block_list(query_mask, b)
     outs = {name: jnp.zeros((c, *sfx), dt) for name, (sfx, dt) in out_specs.items()}
-    if pvary_axes:   # under shard_map: mark the carry varying on those axes
-        outs = {k: _pcast_varying(v, pvary_axes) for k, v in outs.items()}
+    # under shard_map: mark the carry varying on those axes (no-op for ())
+    outs = jax.lax.pcast(outs, pvary_axes, to="varying")
     lane = jnp.arange(r_cap, dtype=jnp.int32)
 
     def body(i, outs):
@@ -852,8 +837,8 @@ def resident_apply(spec: GridSpec,
 
         acc0 = {name: jnp.zeros((b, *sfx), dt)
                 for name, (sfx, dt) in out_specs.items()}
-        if pvary_axes:   # inner carry must match the varying results it sums
-            acc0 = {k: _pcast_varying(v, pvary_axes) for k, v in acc0.items()}
+        # inner carry must match the varying results it sums
+        acc0 = jax.lax.pcast(acc0, pvary_axes, to="varying")
         acc = jax.lax.fori_loop(0, 9, run, acc0)
         new_outs = {}
         for name, val in acc.items():
@@ -989,18 +974,15 @@ def resident_apply_fused(spec: GridSpec,
     outs = {k.name: {name: jnp.zeros((c, *sfx), dt)
                      for name, (sfx, dt) in k.out_specs.items()}
             for k in kernels}
-    if pvary_axes:   # under shard_map: mark the carry varying on those axes
-        outs = {kn: {n: _pcast_varying(v, pvary_axes) for n, v in o.items()}
-                for kn, o in outs.items()}
+    # under shard_map: mark the carry varying on those axes (no-op for ())
+    outs = jax.lax.pcast(outs, pvary_axes, to="varying")
 
     def acc_zeros():
         acc0 = {k.name: {name: jnp.zeros((b, *sfx), dt)
                          for name, (sfx, dt) in k.out_specs.items()}
                 for k in kernels}
-        if pvary_axes:   # inner carry must match the varying results it sums
-            acc0 = {kn: {n_: _pcast_varying(v, pvary_axes)
-                         for n_, v in o.items()} for kn, o in acc0.items()}
-        return acc0
+        # inner carry must match the varying results it sums
+        return jax.lax.pcast(acc0, pvary_axes, to="varying")
 
     def kernel_round(q, nbr, valid, rows, accs):
         new = {}
@@ -1107,8 +1089,8 @@ def phased_chunk_apply(channels: Dict[str, jnp.ndarray],
     # pad so dynamic_slice never clamps (clamping would desync q_slot vs lane_ok)
     qi = jnp.pad(query_idx, (0, n_chunks_max * b - c))
     outs = {name: jnp.zeros((c, *sfx), dt) for name, (sfx, dt) in out_specs.items()}
-    if pvary_axes:   # under shard_map: mark the carry varying on those axes
-        outs = {k: _pcast_varying(v, pvary_axes) for k, v in outs.items()}
+    # under shard_map: mark the carry varying on those axes (no-op for ())
+    outs = jax.lax.pcast(outs, pvary_axes, to="varying")
 
     def body(i, outs):
         sl = i * b
@@ -1126,8 +1108,8 @@ def phased_chunk_apply(channels: Dict[str, jnp.ndarray],
 
         acc0 = {name: jnp.zeros((b, *sfx), dt)
                 for name, (sfx, dt) in out_specs.items()}
-        if pvary_axes:   # inner carry must match the varying results it sums
-            acc0 = {k: _pcast_varying(v, pvary_axes) for k, v in acc0.items()}
+        # inner carry must match the varying results it sums
+        acc0 = jax.lax.pcast(acc0, pvary_axes, to="varying")
         if n_phases == 1:
             acc = phase(jnp.int32(0), acc0)
         else:

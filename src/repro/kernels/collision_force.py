@@ -19,6 +19,13 @@ test. Sentinel (-1) column entries are skipped with ``pl.when`` — the same
 block-skipping mechanism that realizes the paper's static-region optimization
 at block granularity (DESIGN.md §2/O6).
 
+SMEM bound: the scalar-prefetched table lives in SMEM (1 MiB on v5e), but
+the pool's capacity sets its length (N_pad/128 × maxb int32 — 16 MiB at
+8,388,608 slots). The table is therefore walked in slices of at most
+``SMEM_TABLE_ENTRIES`` entries, one kernel launch per slice under
+``lax.map``. Any change to the table's layout must keep one launch's slice
+inside SMEM at every capacity the ladder can reach.
+
 Data layout (TPU-friendly): agents are packed along *lanes*:
   data_t: (8, N_pad) f32 rows = [x, y, z, diameter, type, alive, 0, 0]
   out_t:  (8, N_pad) f32 rows = [fx, fy, fz, nnz, 0, 0, 0, 0]
@@ -38,6 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK = 128
 ROW_X, ROW_Y, ROW_Z, ROW_DIA, ROW_TYPE, ROW_ALIVE = 0, 1, 2, 3, 4, 5
 ROW_FX, ROW_FY, ROW_FZ, ROW_NNZ = 0, 1, 2, 3
+SMEM_TABLE_ENTRIES = 1 << 15   # column-table entries per kernel launch
+                               # (128 KiB of int32 in SMEM)
 
 
 def _force_tile(row: jnp.ndarray, col: jnp.ndarray,
@@ -88,14 +97,15 @@ def _force_tile(row: jnp.ndarray, col: jnp.ndarray,
     return fx, fy, fz, nnz
 
 
-def _kernel(cols_ref,            # scalar prefetch: (n_row_blocks, maxb) int32
+def _kernel(base_ref,            # scalar prefetch: (1,) first row block
+            cols_ref,            # scalar prefetch: (rows * maxb,) int32
             data_row_ref,        # (8, BLOCK) row agents
             data_col_ref,        # (8, BLOCK) candidate col agents
             out_ref,             # (8, BLOCK) accumulated [fx fy fz nnz ...]
-            *, k_rep: float, adhesion, adhesion_band: float):
+            *, maxb: int, k_rep: float, adhesion, adhesion_band: float):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    col_id = cols_ref[i, j]
+    col_id = cols_ref[i * maxb + j]
 
     @pl.when(j == 0)
     def _init():
@@ -106,13 +116,30 @@ def _kernel(cols_ref,            # scalar prefetch: (n_row_blocks, maxb) int32
         row = data_row_ref[...]
         col = data_col_ref[...]
         fx, fy, fz, nnz = _force_tile(
-            row, col, i * BLOCK, col_id * BLOCK,
+            row, col, (base_ref[0] + i) * BLOCK, col_id * BLOCK,
             k_rep, adhesion, adhesion_band)
-        acc = out_ref[...]
-        upd = jnp.zeros_like(acc)
-        upd = upd.at[ROW_FX].set(fx).at[ROW_FY].set(fy)
-        upd = upd.at[ROW_FZ].set(fz).at[ROW_NNZ].set(nnz)
-        out_ref[...] = acc + upd
+        # Mosaic has no in-kernel scatter: build the (8, BLOCK) update by
+        # stacking whole rows, never with .at[].set
+        zero = jnp.zeros_like(fx)
+        out_ref[...] += jnp.stack([fx, fy, fz, nnz, zero, zero, zero, zero])
+
+
+def rows_per_call(maxb: int) -> int:
+    """Row blocks one ``pallas_call`` covers: its slice of the column table
+    (rows × maxb int32) is the scalar-prefetched operand and must fit SMEM
+    (1 MiB on v5e) at any capacity, so the table is cut into slices of at
+    most ``SMEM_TABLE_ENTRIES`` entries."""
+    return max(1, SMEM_TABLE_ENTRIES // maxb)
+
+
+def padded_size(n: int, maxb: int) -> int:
+    """Pool length the kernel runs on: ``n`` rounded up to whole row blocks,
+    and, once the table needs more than one call, to whole calls."""
+    n_rb = -(-n // BLOCK)
+    per = rows_per_call(maxb)
+    if n_rb > per:
+        n_rb = -(-n_rb // per) * per
+    return n_rb * BLOCK
 
 
 def collision_force_kernel(data_t: jnp.ndarray,
@@ -120,29 +147,46 @@ def collision_force_kernel(data_t: jnp.ndarray,
                            *, k_rep: float,
                            adhesion: Optional[Tuple[Tuple[float, ...], ...]],
                            adhesion_band: float,
-                           interpret: bool = True) -> jnp.ndarray:
-    """Run the kernel. data_t: (8, N_pad); block_cols: (N_pad/128, MAXB) int32.
+                           interpret: bool) -> jnp.ndarray:
+    """Run the kernel. data_t: (8, N_pad); block_cols: (N_pad/128, MAXB) int32,
+    with ``N_pad == padded_size(N_pad, MAXB)``.
 
-    Returns out_t (8, N_pad): rows [fx, fy, fz, nnz]. The container is CPU-only,
-    so interpret=True is the validated path; on TPU pass interpret=False.
+    Returns out_t (8, N_pad): rows [fx, fy, fz, nnz]. The column table is
+    walked in slices of ``rows_per_call(MAXB)`` row blocks, one kernel
+    launch per slice under ``lax.map``, so its SMEM footprint is bounded
+    independently of the pool's capacity.
     """
     n_pad = data_t.shape[1]
-    n_row_blocks = n_pad // BLOCK
-    maxb = block_cols.shape[1]
-    kern = functools.partial(_kernel, k_rep=k_rep, adhesion=adhesion,
-                             adhesion_band=adhesion_band)
-    return pl.pallas_call(
+    n_rb, maxb = block_cols.shape
+    if n_rb * BLOCK != n_pad or padded_size(n_pad, maxb) != n_pad:
+        raise ValueError(f"data_t has {n_pad} columns and block_cols "
+                         f"{n_rb} rows; expected padded_size(...) = "
+                         f"{padded_size(n_pad, maxb)} columns")
+    rows = min(n_rb, rows_per_call(maxb))
+    n_calls = n_rb // rows
+    kern = functools.partial(_kernel, maxb=maxb, k_rep=k_rep,
+                             adhesion=adhesion, adhesion_band=adhesion_band)
+    call = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_row_blocks, maxb),
+            num_scalar_prefetch=2,
+            grid=(rows, maxb),
             in_specs=[
-                pl.BlockSpec((8, BLOCK), lambda i, j, cols: (0, i)),
                 pl.BlockSpec((8, BLOCK),
-                             lambda i, j, cols: (0, jnp.maximum(cols[i, j], 0))),
+                             lambda i, j, base, cols: (0, base[0] + i)),
+                pl.BlockSpec((8, BLOCK),
+                             lambda i, j, base, cols:
+                             (0, jnp.maximum(cols[i * maxb + j], 0))),
             ],
-            out_specs=pl.BlockSpec((8, BLOCK), lambda i, j, cols: (0, i)),
+            out_specs=pl.BlockSpec((8, BLOCK),
+                                   lambda i, j, base, cols: (0, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((8, n_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((8, rows * BLOCK), jnp.float32),
         interpret=interpret,
-    )(block_cols, data_t, data_t)
+        name="k1_collision_force",
+    )
+    tables = block_cols.reshape(n_calls, rows * maxb)
+    bases = jnp.arange(n_calls, dtype=jnp.int32)[:, None] * rows
+    outs = jax.lax.map(lambda a: call(a[0], a[1], data_t, data_t),
+                       (bases, tables))                 # (calls, 8, rows·128)
+    return outs.transpose(1, 0, 2).reshape(8, n_pad)

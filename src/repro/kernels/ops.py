@@ -192,7 +192,7 @@ def collision_force_resident(position: jnp.ndarray, diameter: jnp.ndarray,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     c = position.shape[0]
-    n_pad = ((c + BLOCK - 1) // BLOCK) * BLOCK
+    n_pad = k1.padded_size(c, maxb)
     pad = n_pad - c
 
     def padded(x, fill):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..core import EngineConfig, ScenarioParams
 from ..core.behaviors import INFECTED, Infection, RandomWalk
 from ..serve import SimRequest, SimService
+from . import compile_cache
 
 
 def make_service(n_lanes: int, agents: int, side: float) -> SimService:
@@ -86,6 +87,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true",
                     help="resume from the latest checkpoint in --ckpt-dir")
     args = ap.parse_args()
+    compile_cache.enable()
 
     side = args.side or max(40.0, (args.agents ** (1 / 3)) * 5)
     svc = make_service(args.lanes, args.agents, side)

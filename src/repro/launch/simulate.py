@@ -24,6 +24,7 @@ from ..core.behaviors import (Chemotaxis, GrowDivide, Infection, NeuriteGrowth,
                               RandomDeath, RandomWalk, Secretion,
                               GROWTH_CONE, INFECTED)
 from ..core.diffusion import DiffusionSpec
+from . import compile_cache
 
 SCENARIOS = ("proliferation", "clustering", "epidemiology", "neuroscience",
              "oncology")
@@ -115,6 +116,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true",
                     help="resume from the latest checkpoint in --ckpt-dir")
     args = ap.parse_args()
+    compile_cache.enable()
 
     sim, st = build(args.scenario, args.agents, args.force_impl)
     if args.supervised or args.resume:

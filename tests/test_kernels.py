@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
-from repro.kernels import ops, ref
+from repro.kernels import collision_force as k1, ops, ref
 
 # ---------------------------------------------------------------------------
 # K1: collision force
@@ -75,6 +75,34 @@ def test_collision_force_newton(rng):
     """Σ forces = 0 (momentum conservation) when all agents are active."""
     f, nnz, fr, nr = _k1_case(rng, 256, 256, (8, 8, 8), 2.5, ADH)
     np.testing.assert_allclose(np.asarray(f).sum(0), np.zeros(3), atol=1e-3)
+
+
+def test_collision_force_multi_launch_bit_exact(rng, monkeypatch):
+    """The column table cut into several kernel launches — how it stays in
+    SMEM at large capacity — gives the single launch's bits, with the pool
+    padded to whole launches."""
+    n, c = 500, 640                                  # 5 row blocks
+    pos = rng.uniform(0, 12 * 1.5 * 0.99, (n, 3)).astype(np.float32)
+    P = np.zeros((c, 3), np.float32); P[:n] = pos
+    D = np.zeros((c,), np.float32); D[:n] = rng.uniform(0.4, 1.0, n)
+    T = np.zeros((c,), np.int32); T[:n] = rng.integers(0, 2, n)
+    alive = np.zeros((c,), bool); alive[:n] = True
+    args = tuple(jnp.asarray(a) for a in (P, D, T, alive, alive)) + (
+        jnp.zeros(3), jnp.asarray(1.5))
+    kw = dict(dims=(12, 12, 12), k_rep=2.0, adhesion=ADH, adhesion_band=0.4)
+    one = ops.collision_force(*args, **kw)
+    # two row blocks per launch: 5 blocks pad to 6, run as 3 launches
+    monkeypatch.setattr(k1, "SMEM_TABLE_ENTRIES", 2 * 64)
+    assert k1.padded_size(c, 64) == 6 * k1.BLOCK
+    ops.collision_force.clear_cache()
+    try:
+        many = ops.collision_force(*args, **kw)
+    finally:
+        ops.collision_force.clear_cache()
+    assert not bool(many[2])
+    np.testing.assert_array_equal(np.asarray(many[0]), np.asarray(one[0]))
+    np.testing.assert_array_equal(np.asarray(many[1]), np.asarray(one[1]))
+    assert int(np.asarray(one[1]).sum()) > 0
 
 
 # ---------------------------------------------------------------------------
