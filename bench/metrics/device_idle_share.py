@@ -1,0 +1,8 @@
+"""Share of the traced window in which no op ran on the device (%)."""
+
+
+def read(ctx):
+    red = ctx.reduction
+    if red.window_s <= 0 or red.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - red.busy_s / red.window_s)
