@@ -50,10 +50,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax import monitoring  # noqa: E402
 
 from repro.core import (DistConfig, DistributedSimulation,  # noqa: E402
-                        EngineConfig, ForceParams, Simulation)
+                        EngineConfig, ForceParams, Simulation, telemetry)
 from repro.core.behaviors import (INFECTED, Behavior,  # noqa: E402
                                   BehaviorEffects, Infection, RandomWalk)
 from repro.launch import compile_cache, sim_serve, simulate  # noqa: E402
@@ -75,22 +74,6 @@ def check(cond: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileClock:
-    """Seconds the XLA backend spent compiling, from JAX's own monitoring
-    events (tracing and lowering excluded; cache hits add nothing)."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, name, secs, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-
-CLOCK = CompileClock()
-
-
 def peak_bytes() -> int | None:
     """Largest ``peak_bytes_in_use`` over the local devices."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
@@ -104,10 +87,10 @@ def ready(state):
 
 def timed(fn, *args, **kwargs):
     """Run ``fn`` to completion; return (result, wall seconds, seconds of
-    that spent in backend compilation)."""
-    c0, t0 = CLOCK.seconds, time.perf_counter()
+    that spent tracing, lowering and compiling)."""
+    c0, t0 = telemetry.compile_seconds(), time.perf_counter()
     out = ready(fn(*args, **kwargs))
-    return out, time.perf_counter() - t0, CLOCK.seconds - c0
+    return out, time.perf_counter() - t0, telemetry.compile_seconds() - c0
 
 
 # ---------------------------------------------------------------------------
@@ -360,11 +343,11 @@ def print_phase(name: str, out: dict) -> None:
 
 
 def run_phase(name: str, fn) -> None:
-    c0, t0 = CLOCK.seconds, time.perf_counter()
+    c0, t0 = telemetry.compile_seconds(), time.perf_counter()
     fn()
     print(f"phase {name} done " + json.dumps({
         "wall_s": time.perf_counter() - t0,
-        "compile_s": CLOCK.seconds - c0,
+        "compile_s": telemetry.compile_seconds() - c0,
         "peak_bytes_in_use": peak_bytes()}), flush=True)
 
 

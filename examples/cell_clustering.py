@@ -88,7 +88,7 @@ def main():
             skips = 0
             for _ in range(10):
                 state = sim.run(state, 1, check_overflow=True)
-                skips += int(state.stats.rebuild_skips)
+                skips += 1 - int(state.stats.rebuilds)
             pl = (f"  pairs/agent {pairs_per_agent(state):.1f}"
                   f"  reused {skips}/10 steps")
         else:

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from . import compaction, diffusion as diff_mod, forces as force_mod, grid as grid_mod
 from . import health as health_mod, morton, statics as statics_mod
+from . import telemetry
 from .agents import AgentPool, DtypePolicy, make_pool
 from .behaviors import Behavior, BehaviorEffects
 from .stats import StepStats
@@ -421,6 +422,20 @@ def check_kernel_footprints(cfg: EngineConfig, behaviors: Sequence[Behavior],
 
 # -- the iteration core ------------------------------------------------------
 
+# The step's phases, each a ``jax.named_scope`` over its ops. A compiled op
+# belongs to the innermost of these names in its ``op_name`` path (a sweep a
+# behavior calls through ctx.neighbor_apply is the sweep's), or to none.
+# Scopes are metadata: they change no op of the compiled program.
+PHASES = ("grid_build", "pairlist_build", "statics", "diffusion",
+          grid_mod.SWEEP_SCOPE, "behaviors", "health", "commit")
+
+
+def _phase(name: str):
+    if name not in PHASES:
+        raise ValueError(f"{name!r} is not one of {PHASES}")
+    return jax.named_scope(name)
+
+
 def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                         *, owned_channel: Optional[str] = None,
                         pvary_axes: Tuple[str, ...] = (),
@@ -504,10 +519,11 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
     def build_pairs(pool: AgentPool, grid_env) -> Optional[grid_mod.PairList]:
         if pl is None:
             return None
-        return grid_mod.build_pairlist(
-            spec, grid_env, pool.position, pool.alive,
-            radius=pair_radius, max_pairs=pl.max_pairs,
-            chunk=cfg.query_chunk, pvary_axes=pvary_axes)
+        with _phase("pairlist_build"):
+            return grid_mod.build_pairlist(
+                spec, grid_env, pool.position, pool.alive,
+                radius=pair_radius, max_pairs=pl.max_pairs,
+                chunk=cfg.query_chunk, pvary_axes=pvary_axes)
 
     def core(pool: AgentPool, conc: jnp.ndarray, rng: jax.Array,
              it: jnp.ndarray, env: Optional[grid_mod.RebuildState] = None,
@@ -533,76 +549,78 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
         # ---------------- pre standalone ops ----------------
         # Resident envs reorder every build (the permutation IS the §4.2
         # sort); the periodic Morton sort only serves scatter/hash.
-        if cfg.sort_frequency > 0 and cfg.environment in ("scatter_grid",
-                                                          "hash_grid"):
-            pool = jax.lax.cond(it % cfg.sort_frequency == 0,
-                                sort_pool, lambda p: p, pool)
-        rebuilt = jnp.ones((), jnp.int32)
-        pairs = None
-        if not use_cache:
-            res = build_env(cfg, spec, pool, origin, box_size)
-            pool, grid_env = res.pool, res.grid
-            pairs = build_pairs(pool, grid_env)
-        else:
-            # every_k (uniform_grid only, enforced by EngineConfig): rebuild
-            # when the cache is dirty (structural change last step), the k
-            # budget is spent, or accumulated displacement exceeds the bound
-            # the widened cells were sized for — otherwise skip the
-            # permutation + table build outright and query the stale tables
-            # (grid.RebuildPolicy coverage argument). A cached pair list has
-            # its own, euclidean budget: it covers every in-range pair only
-            # while 2·pair_disp ≤ skin (grid.PairListConfig).
-            do_build = (env.dirty | (env.steps_since >= cfg.rebuild.k)
-                        | (env.disp_accum > cfg.rebuild.displacement_bound))
-            if pl is not None:
-                do_build = do_build | (2.0 * env.pair_disp > pl.skin)
-
-            def _fresh(pool, env):
+        with _phase("grid_build"):
+            if cfg.sort_frequency > 0 and cfg.environment in ("scatter_grid",
+                                                              "hash_grid"):
+                pool = jax.lax.cond(it % cfg.sort_frequency == 0,
+                                    sort_pool, lambda p: p, pool)
+            rebuilt = jnp.ones((), jnp.int32)
+            pairs = None
+            if not use_cache:
                 res = build_env(cfg, spec, pool, origin, box_size)
-                return res.pool, grid_mod.RebuildState(
-                    grid=res.grid,
-                    steps_since=jnp.zeros((), jnp.int32),
-                    disp_accum=jnp.zeros((), jnp.float32),
-                    dirty=jnp.zeros((), bool),
-                    pairs=build_pairs(res.pool, res.grid),
-                    pair_disp=(jnp.zeros((), jnp.float32)
-                               if pl is not None else None))
+                pool, grid_env = res.pool, res.grid
+                pairs = build_pairs(pool, grid_env)
+            else:
+                # every_k (uniform_grid only, enforced by EngineConfig): rebuild
+                # when the cache is dirty (structural change last step), the k
+                # budget is spent, or accumulated displacement exceeds the bound
+                # the widened cells were sized for — otherwise skip the
+                # permutation + table build outright and query the stale tables
+                # (grid.RebuildPolicy coverage argument). A cached pair list has
+                # its own, euclidean budget: it covers every in-range pair only
+                # while 2·pair_disp ≤ skin (grid.PairListConfig).
+                do_build = (env.dirty | (env.steps_since >= cfg.rebuild.k)
+                            | (env.disp_accum > cfg.rebuild.displacement_bound))
+                if pl is not None:
+                    do_build = do_build | (2.0 * env.pair_disp > pl.skin)
 
-            pool, env = jax.lax.cond(do_build, _fresh,
-                                     lambda pool, env: (pool, env), pool, env)
-            grid_env = env.grid
-            pairs = env.pairs
-            rebuilt = do_build.astype(jnp.int32)
-        box_overflow = stats.box_overflow
-        box_demand = stats.box_demand
-        if cfg.environment == "uniform_grid":
-            # query exactness bound: every 3-box z-run must fit the run
-            # gather capacity (DESIGN.md §4.2 overflow contract); the demand
-            # is the which-capacity provenance the ladder sizes rungs from
-            box_demand = grid_env.max_run_count.astype(jnp.int32)
-            box_overflow = (grid_env.max_run_count
-                            > spec.run_capacity).astype(jnp.int32)
-        elif cfg.environment == "hash_grid":
-            # same contract: a bucket fuller than the probe gather width
-            # would silently truncate candidates (grid.hash_grid_probe)
-            box_demand = grid_env.max_bucket_count.astype(jnp.int32)
-            box_overflow = (
-                grid_env.max_bucket_count
-                > grid_mod.HASH_K_MULT * spec.max_per_box).astype(jnp.int32)
-        pair_overflow = stats.pair_overflow
-        pair_demand = stats.pair_demand
-        if pairs is not None:
-            # same never-silent contract as the run/bucket capacities: a row
-            # demanding more than max_pairs entries truncated its list; the
-            # demand is the which-capacity provenance the ladder sizes the
-            # max_pairs rung from (§4.2/§4.3)
-            pair_demand = pairs.demand
-            pair_overflow = (pairs.demand > pl.max_pairs).astype(jnp.int32)
+                def _fresh(pool, env):
+                    res = build_env(cfg, spec, pool, origin, box_size)
+                    return res.pool, grid_mod.RebuildState(
+                        grid=res.grid,
+                        steps_since=jnp.zeros((), jnp.int32),
+                        disp_accum=jnp.zeros((), jnp.float32),
+                        dirty=jnp.zeros((), bool),
+                        pairs=build_pairs(res.pool, res.grid),
+                        pair_disp=(jnp.zeros((), jnp.float32)
+                                   if pl is not None else None))
 
-        if cfg.diffusion is not None:
-            sub_dt = dt / cfg.diffusion_substeps
-            for _ in range(cfg.diffusion_substeps):
-                conc = diff_ops.step(conc, sub_dt)
+                pool, env = jax.lax.cond(do_build, _fresh,
+                                         lambda pool, env: (pool, env), pool, env)
+                grid_env = env.grid
+                pairs = env.pairs
+                rebuilt = do_build.astype(jnp.int32)
+            box_overflow = stats.box_overflow
+            box_demand = stats.box_demand
+            if cfg.environment == "uniform_grid":
+                # query exactness bound: every 3-box z-run must fit the run
+                # gather capacity (DESIGN.md §4.2 overflow contract); the demand
+                # is the which-capacity provenance the ladder sizes rungs from
+                box_demand = grid_env.max_run_count.astype(jnp.int32)
+                box_overflow = (grid_env.max_run_count
+                                > spec.run_capacity).astype(jnp.int32)
+            elif cfg.environment == "hash_grid":
+                # same contract: a bucket fuller than the probe gather width
+                # would silently truncate candidates (grid.hash_grid_probe)
+                box_demand = grid_env.max_bucket_count.astype(jnp.int32)
+                box_overflow = (
+                    grid_env.max_bucket_count
+                    > grid_mod.HASH_K_MULT * spec.max_per_box).astype(jnp.int32)
+            pair_overflow = stats.pair_overflow
+            pair_demand = stats.pair_demand
+            if pairs is not None:
+                # same never-silent contract as the run/bucket capacities: a row
+                # demanding more than max_pairs entries truncated its list; the
+                # demand is the which-capacity provenance the ladder sizes the
+                # max_pairs rung from (§4.2/§4.3)
+                pair_demand = pairs.demand
+                pair_overflow = (pairs.demand > pl.max_pairs).astype(jnp.int32)
+
+        with _phase("diffusion"):
+            if cfg.diffusion is not None:
+                sub_dt = dt / cfg.diffusion_substeps
+                for _ in range(cfg.diffusion_substeps):
+                    conc = diff_ops.step(conc, sub_dt)
 
         channels = {k: v for k, v in pool.channels().items()
                     if not k.startswith("extra.")}
@@ -615,10 +633,11 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
         # box-granular aggregation over the grid tables — no extra
         # neighbor sweep (statics.py). Ghost rows carry their owner's
         # bookkeeping, so boundary disturbance crosses shards.
-        if cfg.detect_static and cfg.environment in ("uniform_grid",
-                                                     "brute_force"):
-            static = statics_mod.update_static_flags(pool, spec, grid_env, it)
-            pool = dataclasses.replace(pool, static=static)
+        with _phase("statics"):
+            if cfg.detect_static and cfg.environment in ("uniform_grid",
+                                                         "brute_force"):
+                static = statics_mod.update_static_flags(pool, spec, grid_env, it)
+                pool = dataclasses.replace(pool, static=static)
 
         pos0 = pool.position
         dia0 = pool.diameter
@@ -638,6 +657,7 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             else:
                 active = owned_alive
         nbr_results: Dict[str, Dict[str, jnp.ndarray]] = {}
+        sweep_slots = sweep_candidates = jnp.zeros((), jnp.int32)
         if fused:
             kernels = []
             if cfg.use_forces:
@@ -664,11 +684,17 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                         pairs=pairs)
                     box_overflow = jnp.maximum(box_overflow,
                                                ovf.astype(jnp.int32))
+                    xla_kernels = [k for k in kernels if k.name != "force"]
                 else:
                     nbr_results = grid_mod.resident_apply_fused(
                         spec, grid_env, channels_full, kernels,
                         default_mask=owned_alive, chunk=cfg.query_chunk,
                         pvary_axes=pvary_axes, pairs=pairs)
+                    xla_kernels = kernels
+                # the XLA sweep's work, counted outside its block loop
+                sweep_slots, sweep_candidates = grid_mod.fused_sweep_work(
+                    spec, grid_env, xla_kernels, owned_alive,
+                    chunk=cfg.query_chunk, pairs=pairs)
 
         # ---------------- agent ops: forces ----------------
         force_arr = None                  # kept for the health guard below
@@ -716,95 +742,100 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                 (lambda p: diff_ops.sample(conc, p))
                 if cfg.diffusion else (lambda p: jnp.zeros(p.shape[:-1]))),
         )
-        birth_queues: List[Tuple[Dict[str, jnp.ndarray], jnp.ndarray]] = []
-        death_mask = jnp.zeros((pool.capacity,), bool)
-        for b, bk in zip(behaviors, bkeys):
-            eff = b(ctx, pool, bk)
-            if eff.set_channels:
-                ch = pool.channels()
-                for name, val in eff.set_channels.items():
-                    # behaviors compute in f32/int32; storage keeps the
-                    # pool's policy dtype (DtypePolicy, §4.3)
-                    ch[name] = val.astype(ch[name].dtype)
-                pool = pool.with_channels(ch)
-            if eff.birth_channels is not None:
-                birth_queues.append((eff.birth_channels, eff.birth_valid))
-            if eff.death_mask is not None:
-                death_mask |= eff.death_mask
-            if eff.secretion is not None and cfg.diffusion is not None:
-                conc = diff_ops.add_sources(conc, pool.position,
-                                            eff.secretion)
+        with _phase("behaviors"):
+            birth_queues: List[Tuple[Dict[str, jnp.ndarray], jnp.ndarray]] = []
+            death_mask = jnp.zeros((pool.capacity,), bool)
+            for b, bk in zip(behaviors, bkeys):
+                with jax.named_scope(b.name):
+                    eff = b(ctx, pool, bk)
+                if eff.set_channels:
+                    ch = pool.channels()
+                    for name, val in eff.set_channels.items():
+                        # behaviors compute in f32/int32; storage keeps the
+                        # pool's policy dtype (DtypePolicy, §4.3)
+                        ch[name] = val.astype(ch[name].dtype)
+                    pool = pool.with_channels(ch)
+                if eff.birth_channels is not None:
+                    birth_queues.append((eff.birth_channels, eff.birth_valid))
+                if eff.death_mask is not None:
+                    death_mask |= eff.death_mask
+                if eff.secretion is not None and cfg.diffusion is not None:
+                    conc = diff_ops.add_sources(conc, pool.position,
+                                                eff.secretion)
 
-        # bookkeeping for the next static detection
-        move_d = pool.position - pos0
-        moved = jnp.sum(move_d * move_d, -1) > fp.move_eps ** 2
-        grew = pool.diameter > dia0 + 1e-12
-        pool = dataclasses.replace(pool, moved=moved & pool.alive,
-                                   grew=grew & pool.alive)
-        if use_cache:
-            # budget spent this step: the max per-agent per-axis |Δposition|
-            # (forces + behaviors) — the per-axis bound is what the widened
-            # 3×3×3 stencil coverage argument consumes (grid.RebuildPolicy)
-            step_disp = jnp.max(jnp.where(pool.alive[:, None],
-                                          jnp.abs(move_d), 0.0))
-            if pl is not None:
-                # the pair-list skin argument needs the EUCLIDEAN per-agent
-                # motion (a per-axis max does not bound ‖Δpos‖); the list
-                # stays a superset while 2·pair_disp ≤ skin
-                step_disp_eu = jnp.sqrt(jnp.max(jnp.where(
-                    pool.alive, jnp.sum(move_d * move_d, -1), 0.0)))
+        with _phase("statics"):
+            # bookkeeping for the next static detection
+            move_d = pool.position - pos0
+            moved = jnp.sum(move_d * move_d, -1) > fp.move_eps ** 2
+            grew = pool.diameter > dia0 + 1e-12
+            pool = dataclasses.replace(pool, moved=moved & pool.alive,
+                                       grew=grew & pool.alive)
+            if use_cache:
+                # budget spent this step: the max per-agent per-axis |Δposition|
+                # (forces + behaviors) — the per-axis bound is what the widened
+                # 3×3×3 stencil coverage argument consumes (grid.RebuildPolicy)
+                step_disp = jnp.max(jnp.where(pool.alive[:, None],
+                                              jnp.abs(move_d), 0.0))
+                if pl is not None:
+                    # the pair-list skin argument needs the EUCLIDEAN per-agent
+                    # motion (a per-axis max does not bound ‖Δpos‖); the list
+                    # stays a superset while 2·pair_disp ≤ skin
+                    step_disp_eu = jnp.sqrt(jnp.max(jnp.where(
+                        pool.alive, jnp.sum(move_d * move_d, -1), 0.0)))
 
         # ---------------- health watchdog (§7.5) ----------------
         # One fused reduction over channels the step already materialized;
         # evaluated before the commit phase so slot indices still line up
         # with force_arr/move_d. Observability only — supervisors act on it.
-        health = stats.health
-        if cfg.health is not None and cfg.health.any_enabled:
-            health = health_mod.step_health(
-                cfg.health, owned_of(pool), pool.position, dlo, dhi,
-                force=force_arr, move_d=move_d)
+        with _phase("health"):
+            health = stats.health
+            if cfg.health is not None and cfg.health.any_enabled:
+                health = health_mod.step_health(
+                    cfg.health, owned_of(pool), pool.position, dlo, dhi,
+                    force=force_arr, move_d=move_d)
 
         # ---------------- post standalone ops: commit ----------------
-        # ghosts are the neighbor shard's to kill — only owned deaths commit
-        death_mask &= owned_of(pool)
-        deaths = jnp.sum((death_mask & pool.alive).astype(jnp.int32))
-        pool = dataclasses.replace(pool, alive=pool.alive & ~death_mask)
-        # n_active = force-computed agents still alive at iteration end
-        # (counting at force time could exceed n_live after deaths)
-        n_active = (jnp.sum((active & pool.alive).astype(jnp.int32))
-                    if active is not None
-                    else jnp.sum(owned_of(pool).astype(jnp.int32)))
-        pool = jax.lax.cond(deaths > 0, compaction.compact,
-                            lambda p: p, pool)
+        with _phase("commit"):
+            # ghosts are the neighbor shard's to kill — only owned deaths commit
+            death_mask &= owned_of(pool)
+            deaths = jnp.sum((death_mask & pool.alive).astype(jnp.int32))
+            pool = dataclasses.replace(pool, alive=pool.alive & ~death_mask)
+            # n_active = force-computed agents still alive at iteration end
+            # (counting at force time could exceed n_live after deaths)
+            n_active = (jnp.sum((active & pool.alive).astype(jnp.int32))
+                        if active is not None
+                        else jnp.sum(owned_of(pool).astype(jnp.int32)))
+            pool = jax.lax.cond(deaths > 0, compaction.compact,
+                                lambda p: p, pool)
 
-        births = jnp.zeros((), jnp.int32)
-        birth_overflow = jnp.zeros((), jnp.int32)
-        for q, valid in birth_queues:
-            if owned_channel is not None:
-                # newborns are committed — and later migrated if needed — by
-                # the shard that staged them
-                q = dict(q)
-                q["extra." + owned_channel] = jnp.ones_like(valid)
-            birth_overflow += compaction.birth_overflow(pool, valid)
-            births += jnp.sum(valid.astype(jnp.int32))
-            pool = compaction.commit_births(pool, q, valid, it)
+            births = jnp.zeros((), jnp.int32)
+            birth_overflow = jnp.zeros((), jnp.int32)
+            for q, valid in birth_queues:
+                if owned_channel is not None:
+                    # newborns are committed — and later migrated if needed — by
+                    # the shard that staged them
+                    q = dict(q)
+                    q["extra." + owned_channel] = jnp.ones_like(valid)
+                birth_overflow += compaction.birth_overflow(pool, valid)
+                births += jnp.sum(valid.astype(jnp.int32))
+                pool = compaction.commit_births(pool, q, valid, it)
 
-        if use_cache:
-            # deaths ran the compaction permutation and births appended live
-            # tail slots — either way the cached tables no longer describe
-            # the pool, so the next step must rebuild (never-stale-dead
-            # invariant: stale tables only ever index the layout they were
-            # built over, with every indexed slot still live)
-            env = dataclasses.replace(
-                env,
-                steps_since=env.steps_since + 1,
-                disp_accum=env.disp_accum + step_disp,
-                dirty=(deaths > 0) | (births > 0),
-                **({"pairs": pairs,
-                    "pair_disp": env.pair_disp + step_disp_eu}
-                   if pl is not None else {}))
+            if use_cache:
+                # deaths ran the compaction permutation and births appended live
+                # tail slots — either way the cached tables no longer describe
+                # the pool, so the next step must rebuild (never-stale-dead
+                # invariant: stale tables only ever index the layout they were
+                # built over, with every indexed slot still live)
+                env = dataclasses.replace(
+                    env,
+                    steps_since=env.steps_since + 1,
+                    disp_accum=env.disp_accum + step_disp,
+                    dirty=(deaths > 0) | (births > 0),
+                    **({"pairs": pairs,
+                        "pair_disp": env.pair_disp + step_disp_eu}
+                       if pl is not None else {}))
 
-        n_live_end = jnp.sum(owned_of(pool).astype(jnp.int32))
+            n_live_end = jnp.sum(owned_of(pool).astype(jnp.int32))
         stats = dataclasses.replace(
             stats, n_live=n_live_end,
             n_active=n_active, births=births, deaths=deaths,
@@ -814,7 +845,8 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             # provenance: the capacity rung target)
             capacity_demand=n_live_end + birth_overflow,
             pair_overflow=pair_overflow, pair_demand=pair_demand,
-            rebuilds=rebuilt, rebuild_skips=1 - rebuilt, health=health)
+            rebuilds=rebuilt, health=health, sweep_slots=sweep_slots,
+            sweep_candidates=sweep_candidates)
         return pool, conc, rng, stats, env
 
     return core
@@ -904,9 +936,16 @@ class Simulation:
         respond by raising ``max_per_box`` / ``capacity`` (a recompile, mirroring
         BioDynaMo's dynamic grid growth)."""
         for i in range(n_iterations):
-            state = self._step_fn(state)
+            # host spans on the profiler's clock (free unless it traces)
+            with jax.profiler.TraceAnnotation("sim.step", iteration=i):
+                state = self._step_fn(state)
             if check_overflow:
-                flags = state.stats.flags()
+                with jax.profiler.TraceAnnotation("sim.overflow_check"):
+                    counts = state.stats.totals(StepStats.OVERFLOW_FIELDS
+                                                + StepStats.WORK_FIELDS)
+                telemetry.record_step(
+                    {f: counts[f] for f in StepStats.WORK_FIELDS})
+                flags = {f for f in StepStats.OVERFLOW_FIELDS if counts[f]}
                 if "box_overflow" in flags:
                     if self.config.environment == "hash_grid":
                         raise RuntimeError(

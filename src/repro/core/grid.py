@@ -40,6 +40,8 @@ Alternative environments (paper Fig 11 comparison, DESIGN.md §11.5):
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 import warnings
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
@@ -49,6 +51,20 @@ import numpy as np
 
 from . import compaction, morton
 from .agents import AgentPool
+
+# the named scope of every neighbor sweep (one of engine.PHASES): its ops
+# carry it in their op_name, whoever calls the sweep
+SWEEP_SCOPE = "neighbor_sweep"
+
+
+def sweep_scope(fn):
+    """``fn`` traced under :data:`SWEEP_SCOPE` (compile-time metadata only)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(SWEEP_SCOPE):
+            return fn(*args, **kwargs)
+    return scoped
+
 
 # 27 neighbor offsets of the 3x3x3 cube (static python constant) — used by the
 # scatter/hash environments, whose tables are not contiguous in z.
@@ -773,6 +789,7 @@ def neighbor_apply(spec: GridSpec,
                        pair_fn, out_specs, spec.query_chunk, pvary_axes)
 
 
+@sweep_scope
 def resident_apply(spec: GridSpec,
                    grid: GridState,
                    channels: Dict[str, jnp.ndarray],
@@ -888,6 +905,90 @@ def fused_reads(kernels: Sequence["PairKernel"]) -> Tuple[str, ...]:
     return tuple(order)
 
 
+def _query_masks(kernels: Sequence[PairKernel], default_mask: jnp.ndarray
+                 ) -> Tuple[list, jnp.ndarray]:
+    """Each kernel's query rows, and their union (the fused sweep's blocks)."""
+    masks = [k.query_mask if k.query_mask is not None else default_mask
+             for k in kernels]
+    union_mask = masks[0]
+    for m in masks[1:]:
+        union_mask = union_mask | m
+    return masks, union_mask
+
+
+# the 13 offsets of the 3x3x3 cube after (0, 0, 0) in lexicographic order:
+# with their negatives and (0, 0, 0) itself, all 27
+_HALF_OFFSETS = [tuple(o) for o in _OFFSETS.tolist() if tuple(o) > (0, 0, 0)]
+
+
+def _touching_box_pairs(counts: jnp.ndarray,
+                        dims: Tuple[int, int, int]) -> jnp.ndarray:
+    """Σ over boxes of ``counts[box]`` × the agents in the 27 boxes around it
+    (itself included): the ordered pairs of agents whose boxes touch, each
+    agent paired with itself once. ``counts`` is the flat table, z fastest
+    (morton.linear_encode3). One reduction: each box meets the 13 boxes
+    after it in the 3x3x3 cube through shifted views of the zero-padded
+    table, masked where the shift wraps a row, so no neighborhood table and
+    no 3-D copy of the table is made."""
+    nx, ny, nz = dims
+    m = nx * ny * nz
+    counts = counts.astype(jnp.int32)
+    box = jnp.arange(m, dtype=jnp.int32)
+    coord = (box // (ny * nz), (box // nz) % ny, box % nz)
+    shifts = [(off, (off[0] * ny + off[1]) * nz + off[2])
+              for off in _HALF_OFFSETS
+              if not any(o and n < 2 for o, n in zip(off, dims))]
+    padded = jnp.pad(counts, (0, max([k for _, k in shifts], default=0)))
+    after = jnp.zeros((m,), jnp.int32)
+    for off, k in shifts:                           # k > 0: a later box
+        inside = functools.reduce(operator.and_, [
+            (c + o >= 0) & (c + o < n) for c, o, n in zip(coord, off, dims)])
+        after = after + jnp.where(inside, padded[k:k + m], 0)
+    return jnp.sum(counts * (counts + 2 * after))
+
+
+@sweep_scope
+def fused_sweep_work(spec: GridSpec,
+                     grid: GridState,
+                     kernels: Sequence[PairKernel],
+                     default_mask: jnp.ndarray,
+                     chunk: Optional[int] = None,
+                     pairs: Optional[PairList] = None,
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The work of one :func:`resident_apply_fused` call with these
+    arguments, counted from the tables it reads and not in its block loop
+    (StepStats ``sweep_slots``, ``sweep_candidates``).
+
+    Returns ``(slots, candidates)``, int32 scalars:
+
+      slots:      slots the sweep gathers: the rows of its visited blocks
+                  times the slots per row, 9·R streamed, P from ``pairs``
+      candidates: slots holding a live candidate, self excluded. From a
+                  pair list, its stored entries. Streamed, the ordered
+                  pairs of live agents in touching boxes: the gathered
+                  count when the sweep queries every agent of the grid's
+                  tables and no z-run overflows (StepStats box_overflow);
+                  with a narrower query mask, or ghost rows on a shard, an
+                  upper bound
+    """
+    zero = jnp.zeros((), jnp.int32)
+    if not kernels:
+        return zero, zero
+    c = default_mask.shape[0]
+    b = min(chunk if chunk is not None else spec.query_chunk, c)
+    _, union_mask = _query_masks(kernels, default_mask)
+    _, n_blk = compaction.active_block_list(union_mask, b)
+    if pairs is not None:
+        width = pairs.idx.shape[-1]
+        candidates = jnp.sum(pairs.run_off[:, -1])
+    else:
+        width = 9 * spec.run_capacity
+        candidates = (_touching_box_pairs(grid.counts, spec.dims)
+                      - jnp.sum(grid.counts.astype(jnp.int32)))
+    return n_blk.astype(jnp.int32) * (b * width), candidates.astype(jnp.int32)
+
+
+@sweep_scope
 def resident_apply_fused(spec: GridSpec,
                          grid: GridState,
                          channels: Dict[str, jnp.ndarray],
@@ -959,11 +1060,7 @@ def resident_apply_fused(spec: GridSpec,
     c = channels["position"].shape[0]
     b = min(chunk if chunk is not None else spec.query_chunk, c)
     r_cap = spec.run_capacity
-    masks = [k.query_mask if k.query_mask is not None else default_mask
-             for k in kernels]
-    union_mask = masks[0]
-    for m in masks[1:]:
-        union_mask = union_mask | m
+    masks, union_mask = _query_masks(kernels, default_mask)
     blk_idx, n_blk = compaction.active_block_list(union_mask, b)
     gather_ch = {ch: channels[ch] for ch in reads}      # the pruned stream
     q_src = dict(gather_ch)
@@ -1060,6 +1157,7 @@ def resident_apply_fused(spec: GridSpec,
     return jax.lax.fori_loop(0, n_blk, body, outs)
 
 
+@sweep_scope
 def phased_chunk_apply(channels: Dict[str, jnp.ndarray],
                        gather_channels: Dict[str, jnp.ndarray],
                        query_idx: jnp.ndarray,

@@ -15,7 +15,7 @@ the same way.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -63,14 +63,25 @@ class StepStats:
     pair_demand:      which-capacity provenance for pair_overflow: the
                       largest observed per-agent in-range(+skin) candidate
                       count of the current pair list (0 when disabled)
-    rebuilds:         1 if this step rebuilt its environment (grid build ran)
-    rebuild_skips:    1 if this step reused a cached build instead
-                      (RebuildPolicy mode='every_k'; grid.py). The two split
-                      every step, so their running sums audit the skip rate
+    rebuilds:         1 if this step rebuilt its environment (grid build
+                      ran), 0 if it reused a cached build (RebuildPolicy
+                      mode='every_k'; grid.py): steps minus the running sum
+                      counts the skips
     health:           numerical-health bitmask (health.py: NONFINITE |
                       ESCAPE | DISPLACEMENT), evaluated in-graph by the
                       iteration core. Observability only — supervisors
                       (simcheck.SupervisedRunner) act on it; run() ignores it
+    sweep_slots:      slots the fused XLA neighbor sweep gathered: rows of
+                      its visited blocks × slots per row (9·R streamed, P
+                      from the Verlet pair list); grid.fused_sweep_work
+    sweep_candidates: those that held a live candidate, self excluded;
+                      over sweep_slots it is the share of the gather that
+                      did work. Both are counted from the grid tables and
+                      the pair list, outside the sweep's loop, and are 0 on
+                      paths that run no fused XLA sweep (non-fused
+                      environments, K1 alone). int32: the ceilings are 2^31
+                      slots a step, about 4.9 M agents at 9·48 slots each,
+                      and 2^31 candidates, about 20 M agents at 110 each
     """
 
     n_live: jnp.ndarray
@@ -88,19 +99,22 @@ class StepStats:
     pair_overflow: jnp.ndarray
     pair_demand: jnp.ndarray
     rebuilds: jnp.ndarray
-    rebuild_skips: jnp.ndarray
     health: jnp.ndarray
+    sweep_slots: jnp.ndarray
+    sweep_candidates: jnp.ndarray
 
     FIELDS = ("n_live", "n_active", "births", "deaths", "box_overflow",
               "birth_overflow", "halo_overflow", "migrate_overflow",
               "in_flight", "thin_slab", "box_demand", "capacity_demand",
-              "pair_overflow", "pair_demand",
-              "rebuilds", "rebuild_skips", "health")
+              "pair_overflow", "pair_demand", "rebuilds", "health",
+              "sweep_slots", "sweep_candidates")
 
     # the §4.2 never-silent-loss flags (demands and health are not overflow)
     OVERFLOW_FIELDS = ("box_overflow", "birth_overflow", "halo_overflow",
                        "migrate_overflow", "in_flight", "thin_slab",
                        "pair_overflow")
+    # the sweep's work: counts, so sums over shards or lanes stay counts
+    WORK_FIELDS = ("sweep_slots", "sweep_candidates")
 
     @classmethod
     def zeros(cls, shape: tuple = ()) -> "StepStats":
@@ -136,12 +150,14 @@ class StepStats:
         monitoring code never hand-enumerates the overflow fields again:
         ``if stats.flags(): ...`` / ``sum(stats.flags().values())``.
         """
-        out = {}
-        for f in self.OVERFLOW_FIELDS:
-            v = int(np.asarray(jnp.sum(getattr(self, f))))
-            if v:
-                out[f] = v
-        return out
+        totals = self.totals(self.OVERFLOW_FIELDS)
+        return {f: v for f, v in totals.items() if v}
+
+    def totals(self, fields: Sequence[str]) -> Dict[str, int]:
+        """Host-side: ``fields`` summed over shards or lanes, in one
+        transfer."""
+        values = jax.device_get([getattr(self, f) for f in fields])
+        return {f: int(np.sum(v)) for f, v in zip(fields, values)}
 
     def any_overflow(self) -> bool:
         """Host-side bool form of :meth:`overflowed`."""

@@ -145,6 +145,7 @@ def build_block_cols_from_pairs(pairs: "grid.PairList",
     return cols, jnp.any(ovf)
 
 
+@grid.sweep_scope
 def collision_force_resident(position: jnp.ndarray, diameter: jnp.ndarray,
                              agent_type: jnp.ndarray, alive: jnp.ndarray,
                              active: jnp.ndarray,

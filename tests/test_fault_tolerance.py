@@ -215,7 +215,7 @@ def test_simcheck_roundtrip_every_k_cache(tmp_path):
     assert _same_trees(a, b), \
         "every_k skip schedule must survive the round-trip bit-exactly"
     # rebuild accounting carried over: skip cadence identical
-    assert int(a.stats["rebuild_skips"]) == int(b.stats["rebuild_skips"])
+    assert int(a.stats["rebuilds"]) == int(b.stats["rebuilds"])
 
 
 def test_restore_adapts_env_across_rebuild_modes(tmp_path):

@@ -236,7 +236,7 @@ def test_skin_reuse_step_matches_fresh_streamed():
     skips = 0
     for _ in range(6):
         st = d.step(st)
-        skips += int(st.stats.rebuild_skips)
+        skips += 1 - int(st.stats.rebuilds)
     assert skips > 0, "skin budget should allow at least one reuse step"
     e = Simulation(_cfg(n), [Infection(radius=3.0, beta=0.4, recovery_time=8)])
     st_e = engine.EngineState(pool=st.pool, conc=st.conc, rng=st.rng,

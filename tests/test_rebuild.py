@@ -239,13 +239,13 @@ def test_every_k_skips_and_matches_every_step(rng):
     sa = sim_a.init_state(jnp.asarray(pos), jnp.asarray(dia), jnp.asarray(ids))
     sb = sim_b.init_state(jnp.asarray(pos), jnp.asarray(dia), jnp.asarray(ids))
 
-    steps, rebuilds, skips = 20, 0, 0
+    steps, rebuilds = 20, 0
     for _ in range(steps):
         sa, sb = sim_a.step(sa), sim_b.step(sb)
         assert int(sa.stats["rebuilds"]) == 1    # every_step never skips
+        assert int(sb.stats["rebuilds"]) in (0, 1)   # rebuilt or skipped
         rebuilds += int(sb.stats["rebuilds"])
-        skips += int(sb.stats["rebuild_skips"])
-    assert rebuilds + skips == steps
+    skips = steps - rebuilds
     assert skips > 0, "quiescent forces-only run produced zero skips"
     assert int(sa.stats["n_live"]) == int(sb.stats["n_live"]) == N
     d = float(np.abs(_live_by_id(sa) - _live_by_id(sb)).max())
@@ -354,8 +354,9 @@ _DIST_SCRIPT = textwrap.dedent("""
         rebuilds = skips = 0
         for _ in range(24):
             st = sim.step(st)
-            rebuilds += int(np.sum(np.asarray(st.stats["rebuilds"])))
-            skips += int(np.sum(np.asarray(st.stats["rebuild_skips"])))
+            per_shard = np.asarray(st.stats["rebuilds"])
+            rebuilds += int(np.sum(per_shard))
+            skips += per_shard.size - int(np.sum(per_shard))
         ch = sim.gather_channels(st)
         a = ch["alive"]
         out[name] = ch["position"][a][np.argsort(ch["agent_type"][a])]

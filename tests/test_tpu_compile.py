@@ -7,58 +7,195 @@ and operands that overflow fast memory (the scalar-prefetched column table,
 sized by pool capacity, once outgrew SMEM). Capacities: 65,536 and
 8,388,608, the oncology deployment's capacity at 1,048,576 seed agents.
 
-The topology is described inside a fixture, never at import: only one
-process at a time may load the TPU library, and every test worker imports
-this file.
+The topology is described in a process of its own (``described``), never in
+a test worker: only one process at a time may load the TPU library, and once
+loaded it stays, and adds a device plane with no ops to every later profiler
+trace of its process, which a traced CPU run there would read as a device
+that ran nothing.
 """
 
-import jax
-import jax.numpy as jnp
-import pytest
-from jax.sharding import SingleDeviceSharding
+import contextlib
+import itertools
+import multiprocessing
+import re
+from concurrent.futures import ProcessPoolExecutor
+from unittest import mock
 
-from repro.kernels import collision_force as k1
+import pytest
 
 ADH = ((0.5, 0.1), (0.1, 0.7))
 MAXB = 64
 
+# -- run in the described chip's process --------------------------------------
 
-@pytest.fixture(scope="module")
-def topo():
+_one_chip = None   # a sharding on the described chip, or why there is none
+
+
+def _describe():
+    global _one_chip
+    import jax
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
     try:
-        described = topologies.get_topology_desc(platform="tpu",
-                                                 topology_name="v5e:2x2")
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler to describe with
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        _one_chip = f"no v5e:2x2 topology can be described here: {e}"
+        return
     # compiles for a described chip are written to the persistent cache but
     # cannot be read back without one; keep them out of it
-    was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield described
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    _one_chip = SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
+def _why_not():
+    return _one_chip if isinstance(_one_chip, str) else None
 
 
-@pytest.mark.parametrize("adhesion", [None, ADH], ids=["repulsion", "adhesion"])
-@pytest.mark.parametrize("capacity", [65_536, 8_388_608])
-def test_k1_compiles_for_v5e(one_chip, capacity, adhesion):
+def _k1(capacity, adhesion):
+    """(padded size, custom call present, output bytes) of K1 compiled for
+    the described chip."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import collision_force as k1
+
     n_pad = k1.padded_size(capacity, MAXB)
-    data = jax.ShapeDtypeStruct((8, n_pad), jnp.float32, sharding=one_chip)
+    data = jax.ShapeDtypeStruct((8, n_pad), jnp.float32, sharding=_one_chip)
     cols = jax.ShapeDtypeStruct((n_pad // k1.BLOCK, MAXB), jnp.int32,
-                                sharding=one_chip)
+                                sharding=_one_chip)
     step = jax.jit(lambda d, c: k1.collision_force_kernel(
         d, c, k_rep=2.0, adhesion=adhesion, adhesion_band=0.4,
         interpret=False))
     compiled = step.lower(data, cols).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes == 8 * n_pad * 4
+    return (n_pad, "tpu_custom_call" in compiled.as_text(),
+            compiled.memory_analysis().output_size_in_bytes)
 
+
+def _sir_step_text(scopes=True, counters=True) -> str:
+    """The SIR step at 4,096 agents compiled for the described chip, with
+    or without the engine's phase scopes and the sweep's work counters."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import EngineConfig, Simulation, grid
+    from repro.core.behaviors import Infection, RandomWalk
+
+    with contextlib.ExitStack() as patches:
+        if not scopes:
+            patches.enter_context(mock.patch.object(
+                jax, "named_scope", lambda name: contextlib.nullcontext()))
+        if not counters:
+            zero = jnp.zeros((), jnp.int32)
+            patches.enter_context(mock.patch.object(
+                grid, "fused_sweep_work", lambda *a, **k: (zero, zero)))
+        n, side = 4096, 80.0
+        sim = Simulation(
+            EngineConfig(capacity=n, domain_lo=(0.0,) * 3,
+                         domain_hi=(side,) * 3, interaction_radius=3.0,
+                         use_forces=False, query_chunk=1024),
+            [RandomWalk(sigma=0.8),
+             Infection(radius=3.0, beta=0.25, recovery_time=40)])
+        state = jax.eval_shape(lambda: sim.init_state(
+            jnp.zeros((n, 3), jnp.float32), jnp.ones((n,), jnp.float32)))
+        state = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=_one_chip), state)
+        return sim._step_fn.lower(state).compile().as_text()
+
+
+# -- the tests -----------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def described():
+    """A spawned process holding the described chip; ``submit`` runs one
+    of the functions above there."""
+    spawn = multiprocessing.get_context("spawn")
+    with ProcessPoolExecutor(1, mp_context=spawn,
+                             initializer=_describe) as pool:
+        why = pool.submit(_why_not).result()
+        if why:
+            pytest.skip(why)
+        yield pool
+
+
+@pytest.mark.parametrize("adhesion", [None, ADH], ids=["repulsion", "adhesion"])
+@pytest.mark.parametrize("capacity", [65_536, 8_388_608])
+def test_k1_compiles_for_v5e(described, capacity, adhesion):
+    n_pad, custom_call, out_bytes = described.submit(
+        _k1, capacity, adhesion).result()
+    assert custom_call
+    assert out_bytes == 8 * n_pad * 4
+
+
+def _without_metadata(text: str) -> str:
+    """Compiled HLO without its metadata (source op names, which carry the
+    phase scopes) and the file table."""
+    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+    return re.sub(r"\n\nFileNames\n.*?\n\n\n", "\n\n", text, flags=re.S)
+
+
+def test_phase_scopes_leave_the_v5e_program_unchanged(described):
+    """The engine's named scopes are metadata: without them the chip's
+    compiler emits the same ops, fused the same way."""
+    scoped = described.submit(_sir_step_text).result()
+    unscoped = described.submit(_sir_step_text, scopes=False).result()
+    assert _without_metadata(unscoped) == _without_metadata(scoped)
+
+
+def _sweep_loop(text: str) -> str:
+    """The neighbor sweep's block loop: its condition and body and every
+    computation they call, in order, without metadata, with names numbered
+    by first use, so that a program that only adds work outside the loop
+    gives the same text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name, comps[head.group(1)] = head.group(1), []
+        if name is not None:
+            comps[name].append(line)
+            name = None if line == "}" else name
+    loops = re.findall(r"condition=%(\S+), body=%(\S+), "
+                       r"metadata=\{op_name=\"[^\"]*neighbor_sweep/while\"",
+                       text)
+    assert len(loops) == 1, loops
+    order = []
+
+    def visit(comp):
+        if comp not in order:
+            order.append(comp)
+            for line in comps[comp]:
+                for callee in re.findall(
+                        r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                        line):
+                    visit(callee)
+
+    for comp in loops[0]:
+        visit(comp)
+    lines = []
+    for comp in order:
+        # reads of the loop's tuple cost nothing, and their listed order
+        # varies: list each run of them by tuple index
+        for gte, run in itertools.groupby(
+                comps[comp], lambda line: " get-tuple-element(" in line):
+            run = list(run)
+            lines += sorted(run, key=lambda line: re.sub(
+                r"%[\w.\-]+", "%", line.split(" = ", 1)[1])) if gte else run
+    loop = _without_metadata("\n".join(lines))
+    loop = re.sub(r"\b(param_\d+|arg_tuple)\.\d+", r"\1", loop)
+    ids = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: ids.setdefault(m.group(0), f"%v{len(ids)}"), loop)
+
+
+def test_sweep_counters_leave_the_v5e_sweep_loop_unchanged(described):
+    """The sweep's work counters are computed from the grid tables outside
+    its block loop: without them the chip's compiler emits the same loop,
+    scheduled the same way."""
+    counted = described.submit(_sir_step_text).result()
+    uncounted = described.submit(_sir_step_text, counters=False).result()
+    assert _sweep_loop(counted) == _sweep_loop(uncounted)
+
+
+def test_the_tpu_library_stays_out_of_the_test_process(described):
+    with open("/proc/self/maps") as maps:
+        assert not [line for line in maps if "libtpu" in line]
