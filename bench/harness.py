@@ -13,7 +13,9 @@ device, compiles the step (set-up), then replays a fixed episode of the
 cell's steps from that initial state through ``Simulation.run(state, 1,
 check_overflow=True)`` until ``--seconds`` have passed (the window), reads
 the device's peak memory, frees the program's state and compares the first
-and the last step of the window with the plain reference (``correct``).
+and the last completed step of the window with the plain reference
+(``correct``). The window holds the same device states however many steps
+fit in it (``solo_window``), so the peak does not count them.
 ``--trace 1`` instead traces a few steps of the episode under the profiler
 and reports the cell's per-layer metrics from that trace.
 """
@@ -148,8 +150,10 @@ class Window:
     agent_steps: int = 0
     entering: list = dataclasses.field(default_factory=list)
     seconds: float = 0.0
-    first: tuple = ()             # (state entering, state after) the first
-    last: tuple = ()              # and the last completed step
+    first: tuple = ()             # the first completed step: its (before,
+                                  # after) states, or ``host_pair``'s copy
+    last: tuple = ()              # the last completed step's states, () while
+                                  # the step after it runs or once it failed
 
 
 def solo_window(sim, s0, n0: int, episode: int, *, seconds: float = 0.0,
@@ -159,17 +163,31 @@ def solo_window(sim, s0, n0: int, episode: int, *, seconds: float = 0.0,
 
     ``agent_steps`` sums the live agents entering each completed step; a
     step whose counters carry an overflow or health flag counts as failed
-    (it dropped interactions), and one that raised restarts the episode."""
+    (it dropped interactions), and one that raised restarts the episode.
+
+    At every dispatch the window holds three states on the device, however
+    many steps fit in it: ``s0`` for the resets, the step's input and its
+    output. The last completed step's pair is released before the next
+    dispatch (its ``after`` is that step's input), and the first step's
+    pair is copied to the host before the first dispatch whose input is not
+    its ``after``, with the clock paused: once a run, and never in a window
+    of two steps."""
     w = Window()
     cur, n_in, t_ep = s0, n0, 0
+    paused = 0.0
     t0 = time.perf_counter()
     while True:
+        if w.first and not _on_host(w.first) and cur is not w.first[1]:
+            t_copy = time.perf_counter()
+            w.first = host_pair(*w.first)
+            paused += time.perf_counter() - t_copy
+        w.last, nxt = (), None
         w.attempted += 1
         with spans("run_call"):
             try:
                 nxt = sim.run(cur, 1, check_overflow=True)
             except RuntimeError:
-                nxt = None
+                pass
         if nxt is None:
             w.failed += 1
             w.failed_at.append(t_ep + 1)
@@ -188,7 +206,7 @@ def solo_window(sim, s0, n0: int, episode: int, *, seconds: float = 0.0,
             if t_ep == episode:
                 with spans("episode_reset"):
                     cur, n_in, t_ep = s0, n0, 0
-        w.seconds = time.perf_counter() - t0
+        w.seconds = time.perf_counter() - t0 - paused
         if (steps and w.attempted >= steps) or (not steps
                                                 and w.seconds >= seconds):
             return w
@@ -201,16 +219,26 @@ def live_arrays(state) -> dict:
     return {k: np.asarray(v)[alive] for k, v in ch.items() if k != "alive"}
 
 
+def host_pair(before, after) -> tuple:
+    """A completed step's (before, after) live arrays, with the step's birth
+    count."""
+    arrays = (live_arrays(before), live_arrays(after))
+    arrays[1]["births"] = int(after.stats.births)
+    return arrays
+
+
+def _on_host(pair: tuple) -> bool:
+    return isinstance(pair[0], dict)
+
+
 def checked_steps(win: Window) -> list:
-    """(before, after) live arrays of the window's first and last step,
-    with the step's birth count."""
-    pairs = [win.first] + ([win.last] if win.last is not win.first else [])
-    out = []
-    for before, after in pairs:
-        arrays = (live_arrays(before), live_arrays(after))
-        arrays[1]["births"] = int(after.stats.births)
-        out.append(arrays)
-    return out
+    """(before, after) live arrays of the window's first and last completed
+    step (``host_pair``); the first alone where the window's last dispatch
+    failed, and none where no step completed."""
+    pairs = [win.first] if win.first else []
+    if win.last and win.last is not win.first:
+        pairs.append(win.last)
+    return [p if _on_host(p) else host_pair(*p) for p in pairs]
 
 
 def reference_params(config: dict, dep) -> dict:
@@ -298,8 +326,6 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
         out = {"attempted": win.attempted, "failed": win.failed}
         values = {"agent_steps_per_s": win.agent_steps / win.seconds,
                   "peak_hbm_gib": peak / GIB, "setup_s": setup_s}
-        if not win.last:
-            raise RuntimeError("no step of the window completed")
         checked = checked_steps(win)
         del s0, win, sim                  # the program's state is freed
         checks = check(bench, cell, config, dep, checked, seed)

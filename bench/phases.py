@@ -1,20 +1,17 @@
-"""Device time by phase of the engine's step, and idle gaps named by the
-program's run-loop spans, from the benchmark's trace reduction.
+"""Device time by phase of the engine's step, from the benchmark's trace
+reduction.
 
 The engine wraps each phase of its step in a ``jax.named_scope``
 (``repro.core.engine.PHASES``), so each compiled op's ``op_name`` path
 carries the phases it was issued in. An op belongs to the innermost phase
 in its path, and to ``UNSCOPED`` where there is none: a program without the
-scopes has every op unscoped. ``Simulation.run`` marks each step's dispatch
-``sim.step`` and its flag read ``sim.overflow_check`` on the profiler's
-clock; ``gaps`` names each idle gap by the innermost harness or program
-span around it.
+scopes has every op unscoped.
 """
 
 from __future__ import annotations
 
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 from bench import trace
 
@@ -23,7 +20,6 @@ from bench import trace
 PHASES = ("grid_build", "pairlist_build", "statics", "diffusion",
           "neighbor_sweep", "behaviors", "health", "commit")
 UNSCOPED = "unscoped"
-PROGRAM_SPANS = "sim."          # prefix of the program's own host spans
 
 
 def phase_of(op_name: str) -> str:
@@ -50,22 +46,3 @@ def busy_share(red: trace.Reduction, phase: str) -> Optional[float]:
     if s <= 0:
         return None
     return 100.0 * s / red.busy_s
-
-
-def program_spans(path: str) -> List[trace.Event]:
-    """The program's host spans (names starting ``sim.``) of a trace."""
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(str(path))
-    return [trace.Event(e.name, e.start_ns, e.end_ns)
-            for plane in pd.planes if plane.name.startswith("/host:")
-            for line in plane.lines for e in line.events
-            if e.name.startswith(PROGRAM_SPANS)]
-
-
-def gaps(path: str, hlo_text: str) -> List[Tuple[str, float]]:
-    """The traced window's idle gaps, (innermost harness or program span,
-    seconds)."""
-    ops, spans = trace.load(path)
-    red = trace.reduce_events(ops, spans + program_spans(path),
-                              trace.hlo_kinds(hlo_text))
-    return red.gaps

@@ -77,3 +77,21 @@ def test_idle_gaps_named_by_host_span(reduction):
     top = reduction.breakdown(top=2)
     assert [g[0] for g in top["idle_gaps"]] == ["stats_readout"] * 2
     assert top["device_ops"][0][0] == "sort.0 [sort] jit(sort)/sort"
+
+
+def test_a_device_plane_without_xla_ops_is_no_device(tmp_path):
+    """A CPU trace made in a process that loaded the TPU library holds an
+    empty ``/device:CUSTOM:Megascale Trace`` plane; the ops are still the
+    host's ``hlo_op`` events."""
+    from jax.profiler import ProfileData
+    path = DATA / "cpu_step.xplane.pb"
+    empty = ProfileData.text_proto_to_serialized_xspace(
+        'planes { id: 9 name: "/device:CUSTOM:Megascale Trace" }')
+    with_plane = tmp_path / "with_plane.xplane.pb"
+    with_plane.write_bytes(path.read_bytes() + empty)   # a repeated field
+    assert "/device:CUSTOM:Megascale Trace" in [
+        p.name for p in ProfileData.from_file(str(with_plane)).planes]
+    assert trace.load(str(with_plane)) == trace.load(str(path))
+    hlo = (DATA / "cpu_step.hlo.txt").read_text()
+    assert trace.reduce(str(with_plane), hlo).busy_s == pytest.approx(
+        27_521_854e-9, abs=1e-12)

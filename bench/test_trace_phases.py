@@ -1,5 +1,6 @@
-"""Per-phase device time, the program's host spans and the new readers
-(``bench/phases.py``, ``repro.core.telemetry``).
+"""Per-phase device time, the program's host spans in the trace reduction
+and the new readers (``bench/phases.py``, ``bench/trace.py``,
+``repro.core.telemetry``).
 
 ``testdata/scoped_step.xplane.pb`` traces two calls of one jitted program
 on the CPU: a sort under ``jax.named_scope("grid_build")``, a gather under
@@ -70,7 +71,7 @@ def test_phases_add_up_to_busy_time(by_phase, hlo):
 
 
 def test_gaps_named_by_the_program_spans(hlo):
-    gaps = sorted(phases.gaps(XPLANE, hlo), key=lambda g: -g[1])
+    gaps = sorted(trace.reduce(XPLANE, hlo).gaps, key=lambda g: -g[1])
     # last op of call 1 to the first of call 2; its middle falls in the
     # first sim.overflow_check
     assert gaps[0] == ("sim.overflow_check",
@@ -82,14 +83,43 @@ def test_gaps_named_by_the_program_spans(hlo):
     assert gaps[2] == ("sim.step",
                        pytest.approx((5_398_050 - 16_975) * 1e-9, abs=1e-12))
     assert not {"outside_spans", "run_call"} & {n for n, _ in gaps}
+    names = [n for n, _ in trace.reduce(XPLANE, hlo).breakdown()["idle_gaps"]]
+    assert names[:3] == ["sim.overflow_check", "sim.overflow_check",
+                         "sim.step"]
 
 
 def test_program_spans_are_loaded():
-    names = sorted(s.name for s in phases.program_spans(XPLANE))
-    assert names == sorted(["sim.step", "sim.overflow_check"] * 2)
-    _, spans = trace.load(XPLANE)           # the harness's own, as before
-    assert sorted(s.name for s in spans) == ["run_call", "run_call",
-                                             "window"]
+    _, spans = trace.load(XPLANE)      # the program's beside the harness's
+    assert sorted(s.name for s in spans) == sorted(
+        ["run_call", "run_call", "window"]
+        + ["sim.step", "sim.overflow_check"] * 2)
+
+
+def test_program_spans_leave_the_window_and_phases_unchanged(hlo):
+    """The ``window`` span bounds the reduction: the program's spans only
+    name gaps. The numbers are those of the harness's spans alone, as the
+    reduction read them before it kept the program's."""
+    ops, spans = trace.load(XPLANE)
+    kinds = trace.hlo_kinds(hlo)
+    harness_only = trace.reduce_events(
+        ops, [s for s in spans if s.name in trace.SPANS], kinds)
+    red = trace.reduce(XPLANE, hlo)
+    harness_only.labels = red.labels
+    assert red.by_name == harness_only.by_name
+    assert sorted(s for _, s in red.gaps) == sorted(
+        s for _, s in harness_only.gaps)
+    idle = Bench().module("metrics", "device_idle_share")
+    window_ns, total = 99_095_673 - 16_975, GRID_NS + SWEEP_NS + UNSCOPED_NS
+    for r in (red, harness_only):
+        assert r.window_s == pytest.approx(window_ns * 1e-9, abs=1e-12)
+        assert r.busy_s == pytest.approx(total * 1e-9, abs=1e-12)
+        assert idle.read(_context(r)) == pytest.approx(
+            100.0 * (1 - total / window_ns), rel=1e-9)
+        for phase, ns in (("grid_build", GRID_NS),
+                          ("neighbor_sweep", SWEEP_NS),
+                          (phases.UNSCOPED, UNSCOPED_NS)):
+            assert phases.busy_share(r, phase) == pytest.approx(
+                100.0 * ns / total, rel=1e-9)
 
 
 @pytest.mark.parametrize("label, phase", [

@@ -4,12 +4,16 @@ The JAX profiler writes ``<dir>/plugins/profile/<time>/<host>.xplane.pb``.
 ``load`` reads it with ``jax.profiler.ProfileData``:
 
   * device ops: the events of each device plane's ``XLA Ops`` line
-    (``/device:TPU:n``), named by their HLO instruction; on a backend
-    without device planes (the CPU), the
-    host events that carry an ``hlo_op`` stat, which is how the CPU runtime
-    records each XLA op it runs;
+    (``/device:TPU:n``), named by their HLO instruction; a ``/device:``
+    plane without that line (such as the empty ``/device:CUSTOM:...``
+    plane that loading the TPU library adds to a CPU trace) is no device.
+    On a backend without device planes (the CPU), the host events that
+    carry an ``hlo_op`` stat, which is how the CPU runtime records each XLA
+    op it runs;
   * host spans: the harness's ``jax.profiler.TraceAnnotation`` events
-    (``SPANS``), which name what the host was doing in each idle gap.
+    (``SPANS``) and the program's own (names starting ``sim.``, such as
+    ``Simulation.run``'s ``sim.step`` and ``sim.overflow_check``), which
+    name what the host was doing in each idle gap.
 
 ``hlo_kinds`` classifies each op by the compiled step's own HLO text, so
 that a fusion's kind comes from what it contains: ``gather``, ``scatter``,
@@ -26,6 +30,7 @@ from typing import Dict, FrozenSet, List, Tuple
 
 _EVENT = re.compile(r"^%?([\w.\-]+) = ")
 SPANS = ("window", "run_call", "stats_readout", "episode_reset")
+PROGRAM_SPANS = "sim."          # prefix of the program's own host spans
 KIND_OPCODES = {"gather": "gather", "scatter": "scatter", "sort": "sort"}
 CONTROL = ("while", "conditional", "call")   # their bodies' ops are traced
 
@@ -53,11 +58,13 @@ def op_name(event_name: str) -> str:
 
 
 def load(path: str) -> Tuple[List[Event], List[Event]]:
-    """(device ops, harness host spans) of one trace file."""
+    """(device ops, host spans of the harness and the program) of one trace
+    file."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(str(path))
     planes = list(pd.planes)
-    devices = [p for p in planes if p.name.startswith("/device:")]
+    devices = [p for p in planes if p.name.startswith("/device:")
+               and any(line.name == "XLA Ops" for line in p.lines)]
     ops, spans = [], []
     for plane in devices:
         for line in plane.lines:
@@ -69,7 +76,7 @@ def load(path: str) -> Tuple[List[Event], List[Event]]:
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name in SPANS:
+                if e.name in SPANS or e.name.startswith(PROGRAM_SPANS):
                     spans.append(Event(e.name, e.start_ns, e.end_ns))
                 elif not devices:
                     stats = dict(e.stats)
@@ -218,8 +225,8 @@ def reduce_events(ops: List[Event], spans: List[Event],
     """Busy time is the union of op intervals inside the harness's
     ``window`` span, control ops left out; an op's kind key joins its
     sorted kinds with ``+``
-    (``other`` for none); each idle gap is named by the innermost harness
-    span around its middle."""
+    (``other`` for none); each idle gap is named by the innermost host
+    span around its middle, the harness's or the program's."""
     win = [s for s in spans if s.name == "window"]
     lo = min(s.start for s in win) if win else min(o.start for o in ops)
     hi = max(s.end for s in win) if win else max(o.end for o in ops)
