@@ -113,10 +113,9 @@ def _one_size(n: int) -> dict:
 
     # sequential per-phase sweeps (EngineConfig.fused_sweep=False schedule)
     force_seq = jax.jit(lambda g, ch, m: G.resident_apply(
-        spec, g, ch, m, force_k.pair_fn, force_k.out_specs, cfg.query_chunk))
+        spec, g, ch, m, force_k.pair_fn, force_k.out_specs))
     behav_seq = jax.jit(lambda g, ch, m: G.resident_apply(
-        spec, g, ch, m, infect_k.pair_fn, infect_k.out_specs,
-        cfg.query_chunk))
+        spec, g, ch, m, infect_k.pair_fn, infect_k.out_specs))
     seq_channels = {k: v for k, v in channels.items()
                     if not k.startswith("extra.")}
     us_force = time_fn(force_seq, gs, seq_channels, alive)

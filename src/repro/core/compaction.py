@@ -224,8 +224,13 @@ def active_block_list(active: jnp.ndarray, block: int
     trailing partial range counts as one block. Returns (blk_idx, n_blocks)
     with the tail of ``blk_idx`` padded safely (see active_index_list).
     """
+    return active_index_list(active_blocks(active, block))
+
+
+def active_blocks(active: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(⌈C/block⌉,) bool: which ``block``-sized slot ranges hold ≥1 active
+    agent (the trailing partial range is one)."""
     c = active.shape[0]
     n_blk = (c + block - 1) // block
     pad = n_blk * block - c
-    blk_any = jnp.any(jnp.pad(active, (0, pad)).reshape(n_blk, block), axis=1)
-    return active_index_list(blk_any)
+    return jnp.any(jnp.pad(active, (0, pad)).reshape(n_blk, block), axis=1)

@@ -7,8 +7,8 @@ Iteration structure (paper L2–L19):
                         no-op special case of it), diffusion step, static-flag
                         update (§5, box-granular, from last iteration's
                         bookkeeping)
-  agent ops:            mechanical forces over the *active blocks* only
-                        (§5 skipping at block granularity, run-streaming),
+  agent ops:            mechanical forces over the *active tiles* only
+                        (§5 skipping at tile granularity, windowed sweep),
                         displacement integration, behaviors
   post-standalone ops:  death compaction + birth commit (§3.2), statistics
 
@@ -59,7 +59,7 @@ class EngineConfig:
     fused_sweep: bool = True               # evaluate forces + every declared
                                            # behavior kernel against ONE
                                            # pruned candidate stream per
-                                           # block (grid.resident_apply_fused;
+                                           # tile (grid.resident_apply_fused;
                                            # uniform_grid only — other
                                            # environments run the sequential
                                            # per-phase sweeps). False keeps
@@ -290,10 +290,10 @@ def make_neighbor_apply(cfg: EngineConfig, spec: grid_mod.GridSpec, grid_env,
     Every closure takes ``(pair_fn, out_specs, query_mask=None)`` — the mask
     defaults to ``default_mask`` (the live *owned* set; ghost rows of a
     distributed slab are gather sources, never queries). The uniform grid
-    runs the resident run-streaming loop (grid.resident_apply): contiguous
-    query slices, 9 streamed z-runs at width R, and whole-block skipping
-    driven by the mask (§5/O6 — this is where static blocks drop out of the
-    trip count). The hash grid streams its 27 probes through
+    runs the resident tiled loop (grid.resident_apply): contiguous query
+    tiles, each stencil column read as one window (per-row z-run gathers
+    where a window does not fit), and whole-tile skipping driven by the
+    mask (§5/O6 — this is where static tiles drop out of the trip count). The hash grid streams its 27 probes through
     grid.phased_chunk_apply; scatter ('standard implementation') and brute
     force keep the wide chunk_apply loop.
     """
@@ -305,7 +305,6 @@ def make_neighbor_apply(cfg: EngineConfig, spec: grid_mod.GridSpec, grid_env,
                 query_mask = default_mask
             return grid_mod.resident_apply(spec, grid_env, channels,
                                            query_mask, pair_fn, out_specs,
-                                           cfg.query_chunk,
                                            pvary_axes=pvary_axes)
         return apply
 
@@ -657,7 +656,7 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             else:
                 active = owned_alive
         nbr_results: Dict[str, Dict[str, jnp.ndarray]] = {}
-        sweep_slots = sweep_candidates = jnp.zeros((), jnp.int32)
+        sweep_work = {}                   # StepStats.WORK_FIELDS, if swept
         if fused:
             kernels = []
             if cfg.use_forces:
@@ -692,9 +691,18 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                         pvary_axes=pvary_axes, pairs=pairs)
                     xla_kernels = kernels
                 # the XLA sweep's work, counted outside its block loop
-                sweep_slots, sweep_candidates = grid_mod.fused_sweep_work(
-                    spec, grid_env, xla_kernels, owned_alive,
-                    chunk=cfg.query_chunk, pairs=pairs)
+                sweep_work = dict(zip(StepStats.WORK_FIELDS,
+                                      grid_mod.fused_sweep_work(
+                                          spec, grid_env, xla_kernels,
+                                          owned_alive,
+                                          channels_full["position"],
+                                          chunk=cfg.query_chunk,
+                                          pairs=pairs)))
+                # the later phases read the pool after the sweep: what they
+                # write (the walk's positions, say) is then not live across
+                # the build and the sweep, which sets the step's peak memory
+                pool, nbr_results = jax.lax.optimization_barrier(
+                    (pool, nbr_results))
 
         # ---------------- agent ops: forces ----------------
         force_arr = None                  # kept for the health guard below
@@ -845,8 +853,7 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             # provenance: the capacity rung target)
             capacity_demand=n_live_end + birth_overflow,
             pair_overflow=pair_overflow, pair_demand=pair_demand,
-            rebuilds=rebuilt, health=health, sweep_slots=sweep_slots,
-            sweep_candidates=sweep_candidates)
+            rebuilds=rebuilt, health=health, **sweep_work)
         return pool, conc, rng, stats, env
 
     return core

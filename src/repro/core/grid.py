@@ -21,10 +21,13 @@ layout: no per-step sorted copy of the channels, query chunks are contiguous
 slices, the paper's periodic Morton sort (§4.2) is subsumed (agents in the
 same box are adjacent in memory every step), and — because dead slots carry
 the maximum key — the same permutation is the §3.2 death compaction.
-:func:`resident_apply` then *streams* the 9 z-runs through the pairwise
-reduction one at a time (peak candidate footprint B×R instead of B×9R) and
-skips fully-inactive query blocks outright via a dynamic trip count (paper §5
-static regions at block granularity).
+:func:`resident_apply_fused` then sweeps tiles of consecutive query rows:
+since a stencil column shifts every row's z-run by one key offset, each
+column's candidates for a whole tile lie in one contiguous window of the
+pool, read as a slice and evaluated densely (per-row gathers only for a
+tile whose window does not fit), and skips fully-inactive query blocks
+outright via a dynamic trip count (paper §5 static regions at block
+granularity).
 
 Alternative environments (paper Fig 11 comparison, DESIGN.md §11.5):
   * BruteForceEnvironment — exact O(N²) masked sweep (small N oracle).
@@ -582,22 +585,41 @@ def run_bounds(spec: GridSpec, grid: GridState, query_pos: jnp.ndarray
     endpoints, zero-length where the column falls outside the grid.
     Candidates are *box-level*; callers apply the radius test.
     """
+    return _run_ranges(grid, *_stencil_keys(spec, grid, query_pos))
+
+
+def _stencil_keys(spec: GridSpec, grid: GridState, query_pos: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Per-query key range of the 9 stencil z-runs.
+
+    query_pos: (..., 3). Returns (k_lo, k_hi, inside), each (..., 9): the
+    z-run of column (dx, dy) holds exactly the agents whose box key lies in
+    ``[k_lo, k_hi]`` (uint32, clipped endpoints), and is empty where
+    ``inside`` is False (the column falls outside the grid). Dead slots
+    carry ``morton.DEAD_KEY``, above every ``k_hi``.
+    """
     dims = spec.dims
-    cell = morton.cell_of(query_pos, grid.origin, grid.box_size, dims)   # (Q,3)
+    cell = morton.cell_of(query_pos, grid.origin, grid.box_size, dims)  # (..,3)
     off = jnp.asarray(_RUN_OFFSETS)                                      # (9,2)
-    nx = cell[:, None, 0] + off[None, :, 0]                              # (Q,9)
-    ny = cell[:, None, 1] + off[None, :, 1]
+    nx = cell[..., None, 0] + off[:, 0]                                  # (..,9)
+    ny = cell[..., None, 1] + off[:, 1]
     inside = ((nx >= 0) & (nx < dims[0]) & (ny >= 0) & (ny < dims[1]))
     nx = jnp.clip(nx, 0, dims[0] - 1)
     ny = jnp.clip(ny, 0, dims[1] - 1)
-    z_lo = jnp.maximum(cell[:, 2] - 1, 0)[:, None]                       # (Q,1)
-    z_hi = jnp.minimum(cell[:, 2] + 1, dims[2] - 1)[:, None]
+    z_lo = jnp.maximum(cell[..., 2] - 1, 0)[..., None]                   # (..,1)
+    z_hi = jnp.minimum(cell[..., 2] + 1, dims[2] - 1)[..., None]
     k_lo = morton.linear_encode3(nx, ny, jnp.broadcast_to(z_lo, nx.shape), dims)
     k_hi = morton.linear_encode3(nx, ny, jnp.broadcast_to(z_hi, nx.shape), dims)
-    s = grid.starts[k_lo]                                                # (Q,9)
+    return k_lo, k_hi, inside
+
+
+def _run_ranges(grid: GridState, k_lo, k_hi, inside
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(start, length) in the sorted pool of the key ranges
+    ``[k_lo, k_hi]``, zero-length where not ``inside``."""
+    s = grid.starts[k_lo]
     e = grid.starts[k_hi] + grid.counts[k_hi]
-    n = jnp.where(inside, e - s, 0)
-    return s, n
+    return s, jnp.where(inside, e - s, 0)
 
 
 def build_pairlist(spec: GridSpec, grid: GridState, position: jnp.ndarray,
@@ -685,7 +707,7 @@ def neighbor_runs(spec: GridSpec, grid: GridState, query_pos: jnp.ndarray
 
     query_pos: (Q, 3). Returns (pos, valid): (Q, 9·R) int32 positions into the
     key-sorted pool and bool mask. The wide form of :func:`run_bounds` — hot
-    paths stream the runs one at a time instead (:func:`resident_apply`).
+    paths read the runs as windows instead (:func:`resident_apply`).
     """
     r_cap = spec.run_capacity
     s, n = run_bounds(spec, grid, query_pos)
@@ -796,75 +818,37 @@ def resident_apply(spec: GridSpec,
                    query_mask: jnp.ndarray,
                    pair_fn: Callable,
                    out_specs: Dict[str, Tuple[Tuple[int, ...], jnp.dtype]],
-                   chunk: Optional[int] = None,
                    pvary_axes: Tuple[str, ...] = (),
                    ) -> Dict[str, jnp.ndarray]:
-    """Run-streaming neighbor apply over the RESIDENT grid-ordered pool.
+    """Tiled neighbor apply of one ``pair_fn`` over the RESIDENT pool.
 
     ``channels`` must be in grid-key order (from :func:`build_resident` —
     sorted position == slot id). The loop differs from :func:`chunk_apply`
     in three load-bearing ways (DESIGN.md §3.2):
 
-      * **Contiguous queries.** A query block is a ``dynamic_slice`` of the
+      * **Contiguous queries.** A query tile is a ``dynamic_slice`` of the
         pool, not a gather through an index list; outputs are written back
         with ``dynamic_update_slice``, not scatter-add.
-      * **Run streaming.** The 3×3×3 stencil is consumed as 9 sequential
-        z-run gathers of width R accumulated into the per-block outputs —
-        peak candidate footprint B×R instead of the B×9R materialized
-        matrix, and each gather reads one contiguous span.
-      * **Block-granular static skipping (paper §5 / O6).** Only blocks
+      * **Windows, not gathers.** Each tile of T consecutive query rows reads
+        each of the 9 stencil columns as one contiguous window of W pool
+        slots and evaluates ``pair_fn`` densely on the (T, W) tile; a tile
+        whose window would not fit takes 9 per-row z-run gathers of width R
+        (:func:`resident_apply_fused` has the path and its exactness).
+      * **Tile-granular static skipping (paper §5 / O6).** Only tiles
         containing ≥1 ``query_mask`` row are visited: the trip count is the
-        *dynamic* number of active blocks (compaction.active_block_list).
-        The resident order clusters spatially-quiescent agents into the same
-        blocks, which is what makes the skip rate track the static fraction.
+        *dynamic* number of active tiles (compaction.active_blocks). The
+        resident order clusters spatially-quiescent agents into the same
+        tiles, which is what makes the skip rate track the static fraction.
 
     ``pair_fn`` outputs must be additive across splits of the candidate axis
     (sums/counts — encode an OR-style reduction as a count and threshold it).
-    Outputs are written for ``query_mask`` rows, zeros elsewhere.
+    Outputs are written for ``query_mask`` rows, zeros elsewhere. This is
+    the one-kernel case of :func:`resident_apply_fused`, which it calls, so
+    the two evaluate every tile alike.
     """
-    c = channels["position"].shape[0]
-    b = min(chunk if chunk is not None else spec.query_chunk, c)
-    r_cap = spec.run_capacity
-    blk_idx, n_blk = compaction.active_block_list(query_mask, b)
-    outs = {name: jnp.zeros((c, *sfx), dt) for name, (sfx, dt) in out_specs.items()}
-    # under shard_map: mark the carry varying on those axes (no-op for ())
-    outs = jax.lax.pcast(outs, pvary_axes, to="varying")
-    lane = jnp.arange(r_cap, dtype=jnp.int32)
-
-    def body(i, outs):
-        # clamp the window so a trailing partial block stays in range; overlap
-        # rows recompute identical values (pure per-row function of channels)
-        sl = jnp.minimum(blk_idx[i] * b, c - b)
-        rows = sl + jnp.arange(b, dtype=jnp.int32)                       # (B,)
-        q = {k: jax.lax.dynamic_slice_in_dim(v, sl, b, axis=0)
-             for k, v in channels.items()}
-        qmask = jax.lax.dynamic_slice_in_dim(query_mask, sl, b, axis=0)
-        s, n = run_bounds(spec, grid, q["position"])                     # (B,9)
-        n = jnp.minimum(n, r_cap)
-
-        def run(j, acc):
-            pos = s[:, j, None] + lane                                   # (B,R)
-            valid = lane[None, :] < n[:, j, None]
-            valid &= pos != rows[:, None]          # resident: position == slot
-            pos = jnp.where(valid, pos, 0)
-            nbr = {k: v[pos] for k, v in channels.items()}
-            res = pair_fn(q, nbr, valid, rows)
-            return {name: acc[name] + res[name].astype(acc[name].dtype)
-                    if name in res else acc[name] for name in acc}
-
-        acc0 = {name: jnp.zeros((b, *sfx), dt)
-                for name, (sfx, dt) in out_specs.items()}
-        # inner carry must match the varying results it sums
-        acc0 = jax.lax.pcast(acc0, pvary_axes, to="varying")
-        acc = jax.lax.fori_loop(0, 9, run, acc0)
-        new_outs = {}
-        for name, val in acc.items():
-            val = jnp.where(qmask.reshape((b,) + (1,) * (val.ndim - 1)), val, 0)
-            new_outs[name] = jax.lax.dynamic_update_slice_in_dim(
-                outs[name], val, sl, axis=0)
-        return new_outs
-
-    return jax.lax.fori_loop(0, n_blk, body, outs)
+    kernel = PairKernel("apply", pair_fn, out_specs, reads=tuple(channels))
+    return resident_apply_fused(spec, grid, channels, [kernel], query_mask,
+                                pvary_axes=pvary_axes)["apply"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -947,45 +931,125 @@ def _touching_box_pairs(counts: jnp.ndarray,
     return jnp.sum(counts * (counts + 2 * after))
 
 
+# ---------------------------------------------------------------------------
+# The window path of the streamed sweep (DESIGN.md §3.2)
+# ---------------------------------------------------------------------------
+#
+# The rows of a query tile are consecutive in key order, and a stencil
+# column shifts every row's z-run by the same key offset, so the union of one
+# column's z-runs over the tile is one contiguous range of the sorted pool,
+# holding about as many agents as the tile has rows. The window path reads
+# it as one dynamic_slice of width W and evaluates the pair functions densely
+# on the (T, W) tile, a lane being a candidate iff its key lies in the row's
+# z-run: the set the per-row gather reads. Tiles sit at fixed rows, T and W
+# are constants and a window starts at its first candidate, or ends at the
+# last live agent, so where a candidate lands in its window depends on the
+# live agents alone, not on the pool's capacity: a run grown by the
+# capacity ladder keeps the float sums of a pre-sized one. A tile that
+# holds a dead slot, or one of whose windows would span more than W, takes
+# the per-row gathers, whose lanes start at each run.
+
+WINDOW_TILE = 256          # T: query rows a window serves
+WINDOW = 4 * WINDOW_TILE   # W: slots a window reads; 4T leaves room for a
+                           # tile in a sparse region next to a dense one,
+                           # such as the partly filled last slab of boxes
+
+
+def _window_tables(spec: GridSpec, grid: GridState, position: jnp.ndarray
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The windows of the full tiles, rows ``[k·T, k·T + T)``, from the grid
+    tables and the rows' positions.
+
+    A row's z-run in column (dx, dy) is its own z-run's key range shifted by
+    the column's key offset, so column j's window starts at the lowest
+    z-run key of the tile's rows plus that offset, and ends at the highest
+    plus it (a superset where a shifted row leaves the grid, since it then
+    has no candidates there). A window starts at its first candidate, or
+    W slots before the last live agent where that is earlier. Returns
+    ``(lo, fits)``: (tiles · 9,) int32 first slot of each window,
+    tile-major and flat (a TPU layout pads a minor axis of 9 to 128), and
+    (tiles,) bool — every row of the tile is live, the live agents fill a
+    window, and each column's candidates span at most W slots, so the
+    window path serves the tile.
+    """
+    t, w = WINDOW_TILE, WINDOW
+    full = position.shape[0] // t
+    _, ny, nz = spec.dims
+    m = spec.table_size
+    cell = morton.cell_of(position[:full * t].reshape(full, t, 3),
+                          grid.origin, grid.box_size, spec.dims)
+    own = cell[..., 0] * (ny * nz) + cell[..., 1] * nz
+    k_min = jnp.min(own + jnp.maximum(cell[..., 2] - 1, 0), axis=1)
+    k_max = jnp.max(own + jnp.minimum(cell[..., 2] + 1, nz - 1), axis=1)
+    shift = jnp.asarray([(dx * ny + dy) * nz for dx, dy in _RUN_OFFSETS],
+                        jnp.int32)
+    k_lo = k_min[:, None] + shift                                  # (tiles,9)
+    k_hi = k_max[:, None] + shift
+    lo, span = _run_ranges(grid, jnp.clip(k_lo, 0, m - 1),
+                           jnp.clip(k_hi, 0, m - 1), (k_hi >= 0) & (k_lo < m))
+    live = grid.keys != _DEAD_KEY
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    fits = (jnp.all(live[:full * t].reshape(full, t), axis=1)
+            & (n_live >= w) & jnp.all(span <= w, axis=1))
+    # a window that would pass the last live agent ends there instead: its
+    # start then follows the live agents, not the pool's capacity
+    return jnp.maximum(jnp.minimum(lo, n_live - w), 0).reshape(-1), fits
+
+
 @sweep_scope
 def fused_sweep_work(spec: GridSpec,
                      grid: GridState,
                      kernels: Sequence[PairKernel],
                      default_mask: jnp.ndarray,
+                     position: jnp.ndarray,
                      chunk: Optional[int] = None,
                      pairs: Optional[PairList] = None,
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                     ) -> Tuple[jnp.ndarray, ...]:
     """The work of one :func:`resident_apply_fused` call with these
-    arguments, counted from the tables it reads and not in its block loop
-    (StepStats ``sweep_slots``, ``sweep_candidates``).
+    arguments, counted from the tables it reads and not in its loops
+    (StepStats ``sweep_slots``, ``sweep_candidates``, ``sweep_rows``,
+    ``sweep_window_rows``).
 
-    Returns ``(slots, candidates)``, int32 scalars:
+    Returns ``(slots, candidates, rows, window_rows)``, scalars:
 
-      slots:      slots the sweep gathers: the rows of its visited blocks
-                  times the slots per row, 9·R streamed, P from ``pairs``
-      candidates: slots holding a live candidate, self excluded. From a
-                  pair list, its stored entries. Streamed, the ordered
-                  pairs of live agents in touching boxes: the gathered
-                  count when the sweep queries every agent of the grid's
-                  tables and no z-run overflows (StepStats box_overflow);
-                  with a narrower query mask, or ghost rows on a shard, an
-                  upper bound
+      slots:       lanes the pair functions are evaluated on, float32: per
+                   row 9·W on the window path, 9·R on the gather path, P
+                   from ``pairs``
+      candidates:  int32, lanes holding a live candidate, self excluded.
+                   From a pair list, its stored entries. Streamed, the
+                   ordered pairs of live agents in touching boxes: the
+                   evaluated count when the sweep queries every agent of
+                   the grid's tables and no z-run overflows (StepStats
+                   box_overflow); with a narrower query mask, or ghost rows
+                   on a shard, an upper bound
+      rows:        int32, query rows evaluated: T a visited tile, and the
+                   rows past the last full tile (the rows of the visited
+                   blocks from a pair list)
+      window_rows: int32, those of them on the window path
     """
     zero = jnp.zeros((), jnp.int32)
     if not kernels:
-        return zero, zero
+        return jnp.zeros((), jnp.float32), zero, zero, zero
     c = default_mask.shape[0]
-    b = min(chunk if chunk is not None else spec.query_chunk, c)
     _, union_mask = _query_masks(kernels, default_mask)
-    _, n_blk = compaction.active_block_list(union_mask, b)
     if pairs is not None:
-        width = pairs.idx.shape[-1]
-        candidates = jnp.sum(pairs.run_off[:, -1])
-    else:
-        width = 9 * spec.run_capacity
-        candidates = (_touching_box_pairs(grid.counts, spec.dims)
-                      - jnp.sum(grid.counts.astype(jnp.int32)))
-    return n_blk.astype(jnp.int32) * (b * width), candidates.astype(jnp.int32)
+        b = min(chunk if chunk is not None else spec.query_chunk, c)
+        _, n_blk = compaction.active_block_list(union_mask, b)
+        rows = n_blk.astype(jnp.int32) * b
+        slots = rows.astype(jnp.float32) * pairs.idx.shape[-1]
+        return (slots, jnp.sum(pairs.run_off[:, -1]).astype(jnp.int32),
+                rows, zero)
+    t = WINDOW_TILE
+    full = c // t
+    _, fits = _window_tables(spec, grid, position)
+    visited = compaction.active_blocks(union_mask[:full * t], t)
+    rows = jnp.sum(visited, dtype=jnp.int32) * t + c % t
+    win = jnp.sum(visited & fits, dtype=jnp.int32) * t
+    slots = 9.0 * (WINDOW * win.astype(jnp.float32)
+                   + spec.run_capacity * (rows - win).astype(jnp.float32))
+    candidates = (_touching_box_pairs(grid.counts, spec.dims)
+                  - jnp.sum(grid.counts.astype(jnp.int32)))
+    return slots, candidates.astype(jnp.int32), rows, win
 
 
 @sweep_scope
@@ -998,29 +1062,56 @@ def resident_apply_fused(spec: GridSpec,
                          pvary_axes: Tuple[str, ...] = (),
                          pairs: Optional[PairList] = None,
                          ) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Multi-kernel :func:`resident_apply`: ONE candidate stream per block.
+    """Multi-kernel neighbor sweep: ONE candidate stream per query tile.
 
-    The single-kernel loop re-gathers the 9 z-runs once per phase (forces,
-    each neighbor behavior, ...). Here every registered :class:`PairKernel`
-    is evaluated against the *same* per-run gather, and that gather is pruned
-    to the union of the declared footprints — an SIR run never streams
-    ``diameter``, a forces-only run never streams infection timers. Peak
-    per-block candidate memory drops from ``phases × B×R×|channels|`` streams
-    to ``1 × B×R×|union reads|``, and the pass count over the pool from one
-    per phase to one total.
+    Every registered :class:`PairKernel` is evaluated against the *same*
+    candidate reads, pruned to the union of the declared footprints — an
+    SIR run never streams ``diameter``, a forces-only run never streams
+    infection timers — and the pool is passed over once per step, not once
+    per phase.
+
+    **Streamed mode** (DESIGN.md §3.2). The pool is swept in tiles of
+    T = :data:`WINDOW_TILE` rows at fixed offsets ``k·T``, those holding a
+    query row (paper §5 static regions at tile granularity: the trip count
+    is the dynamic number of such tiles). One loop runs every visited tile
+    through the window path; a second, over just the visited tiles whose
+    windows do not fit (:func:`_window_tables`, from the tables outside the
+    loops), runs them again through the gather path, which overwrites their
+    rows; the rows past the last full tile take the gather path too:
+
+      * **Window path.** For each of the 9 stencil columns, the z-runs of
+        the tile's rows all lie in one slice of W pool slots; the read
+        channels and keys are read as that ``dynamic_slice`` and each
+        pair_fn runs on the dense (T, W) tile, neighbor channels broadcast
+        from (W, ...). A lane is valid iff its key lies in the row's
+        z-run ``[k_lo, k_hi]`` of the column (a dead slot's key lies above
+        every run) and it is not the row itself: exactly the candidates
+        the gather path reads, whenever no z-run holds more than R agents
+        (the only case that is not flagged, StepStats box_overflow).
+      * **Gather path**, for a tile that holds a dead slot or one of whose
+        windows would span more than W = :data:`WINDOW` slots: per row, 9
+        z-run gathers of width R (:func:`run_bounds`, truncated at R and
+        flagged past it).
+
+    Neither path's lanes depend on the pool's capacity (a window starts at
+    its first candidate, or ends at the last live agent; a gather starts at
+    its run), so a capacity-ladder rewind stays bit-exact with a pre-sized
+    run.
 
     Parity vs sequential single-kernel sweeps (tests/test_fused.py):
 
-      * The block list is driven by the OR of the kernels' query masks. A
-        block visited by both paths sees the identical slice offset, run
-        bounds, gather and run accumulation order, so each kernel's outputs
-        on its own mask rows are **bit-exact** vs its sequential sweep.
-      * A block visited only for another kernel's sake writes zeros for this
+      * The tile list is driven by the OR of the kernels' query masks. A
+        tile visited by both sees the identical rows, windows (which
+        depend on the rows' positions and keys, not on the masks), reads
+        and column accumulation order, so each kernel's outputs on its own
+        mask rows are **bit-exact** vs its sequential sweep
+        (:func:`resident_apply`, this function with one kernel).
+      * A tile visited only for another kernel's sake writes zeros for this
         kernel (its mask slice is all-False there) — identical to the
         sequential path never visiting it.
 
     **from_pairlist mode** (``pairs`` given, DESIGN.md §3.4): instead of
-    streaming the 9 z-runs at width R, gather the row's pruned candidates
+    reading the 9 stencil columns, gather the row's pruned candidates
     ONCE at width P = pairs.idx.shape[-1] and evaluate each kernel once per
     run *segment* of the packed table. Parity vs the streamed sweep:
 
@@ -1036,8 +1127,9 @@ def resident_apply_fused(spec: GridSpec,
         float outputs agree to the last bit in almost every row — but not
         unconditionally: XLA:CPU lowers the lane-axis ``jnp.sum`` inside a
         pair_fn to a lane-POSITION-sensitive partial-accumulator scheme, so
-        packing bit-equal addends into different lanes (or a different
-        width P ≠ R) can regroup a near-cancelling row's sum by 1-2 ulp.
+        packing bit-equal addends into different lanes (a pair-list
+        segment of width P against a window of width W) can regroup a
+        near-cancelling row's sum by 1-2 ulp.
         Same-mode comparisons (ladder rewind vs pre-sized, shard counts,
         the Pallas block map) share one layout and stay fully bit-exact.
       * Under every_k reuse (skin>0, 2·pair_disp ≤ skin) the listed set is
@@ -1058,14 +1150,12 @@ def resident_apply_fused(spec: GridSpec,
         raise KeyError(f"PairKernel footprint names channels not in the pool: "
                        f"{missing} (have {sorted(channels)})")
     c = channels["position"].shape[0]
-    b = min(chunk if chunk is not None else spec.query_chunk, c)
     r_cap = spec.run_capacity
     masks, union_mask = _query_masks(kernels, default_mask)
-    blk_idx, n_blk = compaction.active_block_list(union_mask, b)
     gather_ch = {ch: channels[ch] for ch in reads}      # the pruned stream
     q_src = dict(gather_ch)
     if pairs is None:
-        q_src.setdefault("position", channels["position"])  # run_bounds
+        q_src.setdefault("position", channels["position"])  # _stencil_keys
     lane = jnp.arange(r_cap, dtype=jnp.int32)
 
     outs = {k.name: {name: jnp.zeros((c, *sfx), dt)
@@ -1074,8 +1164,8 @@ def resident_apply_fused(spec: GridSpec,
     # under shard_map: mark the carry varying on those axes (no-op for ())
     outs = jax.lax.pcast(outs, pvary_axes, to="varying")
 
-    def acc_zeros():
-        acc0 = {k.name: {name: jnp.zeros((b, *sfx), dt)
+    def acc_zeros(rows):
+        acc0 = {k.name: {name: jnp.zeros((rows, *sfx), dt)
                          for name, (sfx, dt) in k.out_specs.items()}
                 for k in kernels}
         # inner carry must match the varying results it sums
@@ -1097,13 +1187,15 @@ def resident_apply_fused(spec: GridSpec,
             ko = {}
             for name, val in accs[k.name].items():
                 val = jnp.where(
-                    km.reshape((b,) + (1,) * (val.ndim - 1)), val, 0)
+                    km.reshape(km.shape + (1,) * (val.ndim - 1)), val, 0)
                 ko[name] = jax.lax.dynamic_update_slice_in_dim(
                     outs[k.name][name], val, sl, axis=0)
             new_outs[k.name] = ko
         return new_outs
 
     if pairs is not None:
+        b = min(chunk if chunk is not None else spec.query_chunk, c)
+        blk_idx, n_blk = compaction.active_block_list(union_mask, b)
         p = pairs.idx.shape[-1]
         lane_p = jnp.arange(p, dtype=jnp.int32)
 
@@ -1126,35 +1218,82 @@ def resident_apply_fused(spec: GridSpec,
                 valid = (lane_p[None, :] >= lo) & (lane_p[None, :] < hi)
                 return kernel_round(q, nbr, valid, rows, accs)
 
-            accs = jax.lax.fori_loop(0, 9, run, acc_zeros())
+            accs = jax.lax.fori_loop(0, 9, run, acc_zeros(b))
             return writeback(outs, accs, kmasks, sl)
 
         return jax.lax.fori_loop(0, n_blk, body, outs)
 
-    def body(i, outs):
-        # clamp the window so a trailing partial block stays in range; overlap
-        # rows recompute identical values (pure per-row function of channels)
-        sl = jnp.minimum(blk_idx[i] * b, c - b)
-        rows = sl + jnp.arange(b, dtype=jnp.int32)                       # (B,)
-        q = {ch: jax.lax.dynamic_slice_in_dim(v, sl, b, axis=0)
-             for ch, v in q_src.items()}
-        kmasks = [jax.lax.dynamic_slice_in_dim(m, sl, b, axis=0)
-                  for m in masks]
-        s, n = run_bounds(spec, grid, q["position"])                     # (B,9)
+    t, w = WINDOW_TILE, WINDOW
+    full = c // t                             # tiles; c % t rows follow
+    lo_tab, fits_tab = _window_tables(spec, grid, channels["position"])
+    lane_w = jnp.arange(w, dtype=jnp.int32)
+
+    def window_path(q, rows, lo, accs):
+        # column j's candidates of every row lie in ONE slice of the pool
+        k_lo, k_hi, inside = _stencil_keys(spec, grid, q["position"])    # (T,9)
+
+        def column(j, accs):
+            start = jax.lax.dynamic_index_in_dim(lo, j, keepdims=False)
+            col = lambda a: jax.lax.dynamic_index_in_dim(a, j, axis=1)
+            keys = jax.lax.dynamic_slice_in_dim(grid.keys, start, w)
+            valid = (col(inside) & (keys >= col(k_lo)) & (keys <= col(k_hi))
+                     & (start + lane_w != rows[:, None]))
+            nbr = {}
+            for ch, v in gather_ch.items():
+                win = jax.lax.dynamic_slice_in_dim(v, start, w, axis=0)
+                nbr[ch] = jnp.broadcast_to(win[None], (t,) + win.shape)
+            return kernel_round(q, nbr, valid, rows, accs)
+
+        return jax.lax.fori_loop(0, 9, column, accs)
+
+    def gather_path(q, rows, lo, accs):
+        # the per-row z-run gathers, for rows no window serves
+        s, n = run_bounds(spec, grid, q["position"])                     # (T,9)
         n = jnp.minimum(n, r_cap)
 
         def run(j, accs):
-            pos = s[:, j, None] + lane                                   # (B,R)
+            pos = s[:, j, None] + lane                                   # (T,R)
             valid = lane[None, :] < n[:, j, None]
             valid &= pos != rows[:, None]          # resident: position == slot
             pos = jnp.where(valid, pos, 0)
             nbr = {ch: v[pos] for ch, v in gather_ch.items()}  # ONE gather
             return kernel_round(q, nbr, valid, rows, accs)
 
-        accs = jax.lax.fori_loop(0, 9, run, acc_zeros())
+        return jax.lax.fori_loop(0, 9, run, accs)
+
+    def sweep_rows(path, outs, sl, n, lo=None):
+        q = {ch: jax.lax.dynamic_slice_in_dim(v, sl, n, axis=0)
+             for ch, v in q_src.items()}
+        rows = sl + jnp.arange(n, dtype=jnp.int32)                       # (n,)
+        accs = path(q, rows, lo, acc_zeros(n))
+        kmasks = [jax.lax.dynamic_slice_in_dim(m, sl, n, axis=0)
+                  for m in masks]
         return writeback(outs, accs, kmasks, sl)
 
-    return jax.lax.fori_loop(0, n_blk, body, outs)
+    def sweep_tiles(path, tiles, n_tiles, outs):
+        # the loop is named for its path (op_name .../<path>/while)
+        def body(i, outs):
+            k = tiles[i]
+            return sweep_rows(path, outs, k * t, t,
+                              jax.lax.dynamic_slice_in_dim(lo_tab, k * 9, 9))
+
+        with jax.named_scope(path.__name__):
+            return jax.lax.fori_loop(0, n_tiles, body, outs)
+
+    # every visited tile through its windows; then the visited tiles whose
+    # windows do not fit again, through the per-row gathers (all of them in
+    # a pool smaller than a window); then the rows past the last full tile
+    if full:
+        visited = compaction.active_blocks(union_mask[:full * t], t)
+        if c >= w:
+            outs = sweep_tiles(window_path,
+                               *compaction.active_index_list(visited), outs)
+            visited &= ~fits_tab
+        outs = sweep_tiles(gather_path,
+                           *compaction.active_index_list(visited), outs)
+    if c % t:
+        outs = sweep_rows(gather_path, outs, full * t, c % t)
+    return outs
 
 
 @sweep_scope

@@ -71,17 +71,28 @@ class StepStats:
                       ESCAPE | DISPLACEMENT), evaluated in-graph by the
                       iteration core. Observability only — supervisors
                       (simcheck.SupervisedRunner) act on it; run() ignores it
-    sweep_slots:      slots the fused XLA neighbor sweep gathered: rows of
-                      its visited blocks × slots per row (9·R streamed, P
-                      from the Verlet pair list); grid.fused_sweep_work
-    sweep_candidates: those that held a live candidate, self excluded;
-                      over sweep_slots it is the share of the gather that
-                      did work. Both are counted from the grid tables and
-                      the pair list, outside the sweep's loop, and are 0 on
-                      paths that run no fused XLA sweep (non-fused
-                      environments, K1 alone). int32: the ceilings are 2^31
-                      slots a step, about 4.9 M agents at 9·48 slots each,
-                      and 2^31 candidates, about 20 M agents at 110 each
+    sweep_slots:      lanes the fused XLA neighbor sweep evaluated its
+                      pair functions on: per row of its evaluated tiles,
+                      9·W on the window path and 9·R on the gather path
+                      (grid.window_shape), P from the Verlet pair list.
+                      float32: at 9·1,024 lanes a row, int32's 2^31
+                      passes at about 233,000 rows; exact below 2^24,
+                      within 6e-8 of the count above
+    sweep_candidates: lanes that held a live candidate, self excluded;
+                      over sweep_slots it is the share of the evaluated
+                      lanes that did work. int32: 2^31 candidates is
+                      about 20 M agents at 110 each
+    sweep_rows:       query rows the fused XLA sweep evaluated: T per
+                      tile of its visited blocks (block rows from a pair
+                      list)
+    sweep_window_rows: those of them on the window path (grid.py,
+                      resident_apply_fused); over sweep_rows it is the
+                      share the window path served. The four sweep
+                      counters come from the grid tables and the pair
+                      list, outside the sweep's loop
+                      (grid.fused_sweep_work), and are 0 on paths that
+                      run no fused XLA sweep (non-fused environments, K1
+                      alone)
     """
 
     n_live: jnp.ndarray
@@ -102,23 +113,28 @@ class StepStats:
     health: jnp.ndarray
     sweep_slots: jnp.ndarray
     sweep_candidates: jnp.ndarray
+    sweep_rows: jnp.ndarray
+    sweep_window_rows: jnp.ndarray
 
     FIELDS = ("n_live", "n_active", "births", "deaths", "box_overflow",
               "birth_overflow", "halo_overflow", "migrate_overflow",
               "in_flight", "thin_slab", "box_demand", "capacity_demand",
               "pair_overflow", "pair_demand", "rebuilds", "health",
-              "sweep_slots", "sweep_candidates")
+              "sweep_slots", "sweep_candidates", "sweep_rows",
+              "sweep_window_rows")
 
     # the §4.2 never-silent-loss flags (demands and health are not overflow)
     OVERFLOW_FIELDS = ("box_overflow", "birth_overflow", "halo_overflow",
                        "migrate_overflow", "in_flight", "thin_slab",
                        "pair_overflow")
     # the sweep's work: counts, so sums over shards or lanes stay counts
-    WORK_FIELDS = ("sweep_slots", "sweep_candidates")
+    WORK_FIELDS = ("sweep_slots", "sweep_candidates", "sweep_rows",
+                   "sweep_window_rows")
 
     @classmethod
     def zeros(cls, shape: tuple = ()) -> "StepStats":
-        return cls(**{f: jnp.zeros(shape, jnp.int32) for f in cls.FIELDS})
+        return cls(**{f: jnp.zeros(shape, jnp.float32 if f == "sweep_slots"
+                                   else jnp.int32) for f in cls.FIELDS})
 
     # dict-style access so both engines' stats read identically
     def __getitem__(self, key: str) -> jnp.ndarray:

@@ -50,8 +50,7 @@ def _forces_all_envs(pool, spec, radius, channels, pair):
     rpool, rgs, order = rres.pool, rres.grid, rres.order
     rch = {k: v for k, v in rpool.channels().items()
            if not k.startswith("extra.")}
-    res = G.resident_apply(spec, rgs, rch, rpool.alive, pair, OUT_SPECS,
-                           spec.query_chunk)
+    res = G.resident_apply(spec, rgs, rch, rpool.alive, pair, OUT_SPECS)
     out["uniform_resident"] = {
         name: jnp.zeros_like(val).at[order].set(val)
         for name, val in res.items()}

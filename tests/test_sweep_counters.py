@@ -1,17 +1,23 @@
 """The fused sweep's work counters (StepStats ``sweep_*``) against a plain
 numpy count.
 
-One SIR step (Infection alone: no forces, no walk) of a small uniform
-population, in the streamed and in the pair-list mode of
-grid.resident_apply_fused. Capacity equals the population and the query
-block divides it, so every block is visited and every row is a live agent:
-``sweep_slots`` is the population times the slots per row, and
-``sweep_candidates`` the number of (agent, other agent) pairs whose grid
+One SIR step (Infection alone: no forces, no walk) of a population of 4,096,
+in the streamed and in the pair-list mode of grid.resident_apply_fused.
+Capacity equals the population, so every row is a live agent and every
+query tile or block is visited. Streamed, the sweep takes the pool in tiles
+of T = grid.WINDOW_TILE rows; a tile takes the window path when, for each of
+the 9 stencil columns, the sorted agents whose boxes lie between its rows'
+lowest and highest z-run keys, shifted to that column, number at most
+W = grid.WINDOW, and the per-row gathers of width R otherwise. So
+``sweep_rows`` is the population, ``sweep_window_rows`` the rows of the
+tiles that fit, and ``sweep_slots`` 9·W lanes a row of those and 9·R of the
+others (a clustered population has both);
+``sweep_candidates`` is the number of (agent, other agent) pairs whose grid
 boxes touch (the 3×3×3 boxes around the agent's own), or, from the pair
 list, those of them within the list's radius, at most ``max_pairs`` an
-agent. A step that runs no fused sweep leaves both at 0. Lanes of the
-ensemble count their own populations, so summed over lanes the counters
-and their ratio stay those of the whole sweep.
+agent, over P lanes a row. A step that runs no fused sweep leaves all at 0.
+Lanes of the ensemble count their own populations, so summed over lanes
+the counters and their ratios stay those of the whole sweep.
 """
 
 import itertools
@@ -19,10 +25,11 @@ import itertools
 import numpy as np
 import pytest
 
-from repro.core import EngineConfig, EnsembleEngine, Simulation, grid
+from repro.core import (EngineConfig, EnsembleEngine, Simulation, StepStats,
+                        grid)
 from repro.core.behaviors import INFECTED, Infection
 
-N, SIDE, CHUNK, MAX_PAIRS = 1024, 40.0, 256, 48
+N, SIDE, CHUNK, MAX_PAIRS = 4096, 64.0, 256, 48
 
 
 def _cfg(mode):
@@ -34,9 +41,12 @@ def _cfg(mode):
                   if mode == "pairlist" else None))
 
 
-def _population(seed):
+def _population(seed, clustered=False):
     rng = np.random.default_rng(seed)
     pos = rng.uniform(0.5, SIDE - 0.5, (N, 3)).astype(np.float32)
+    if clustered:       # 60% in one x-slab of boxes, the rest around it
+        dense = rng.random(N) < 0.6
+        pos[dense, 0] = rng.uniform(30.5, 32.5, dense.sum())
     types = np.where(np.arange(N) < N // 10, INFECTED, 0).astype(np.int32)
     return (pos, np.ones(N, np.float32), types,
             {"infect_timer": np.full(N, 8, np.int32)})
@@ -46,21 +56,58 @@ def _behaviors():
     return [Infection(radius=3.0, beta=0.5, recovery_time=8)]
 
 
+def _window_rows(cell, dims):
+    """Rows of the full tiles of the key-sorted cells each of whose column
+    windows spans at most W agents (in a population of at least W)."""
+    t, w = grid.WINDOW_TILE, grid.WINDOW
+    nx, ny, nz = dims
+    own = cell[:, 0] * ny * nz + cell[:, 1] * nz
+    keys = own + cell[:, 2]
+    lo_key = own + np.maximum(cell[:, 2] - 1, 0)
+    hi_key = own + np.minimum(cell[:, 2] + 1, nz - 1)
+    rows = 0
+    for first in range(0, len(keys) - t + 1, t):
+        fits = True
+        for dx, dy in itertools.product((-1, 0, 1), repeat=2):
+            shift = (dx * ny + dy) * nz
+            lo = lo_key[first:first + t].min() + shift
+            hi = hi_key[first:first + t].max() + shift
+            span = (np.searchsorted(keys, min(hi, nx * ny * nz - 1),
+                                    "right")
+                    - np.searchsorted(keys, max(lo, 0)))
+            if hi >= 0 and lo < nx * ny * nz:
+                fits &= span <= w
+        rows += t * (fits and len(keys) >= w)
+    return rows
+
+
 def _plain_count(pos, cfg, mode):
-    """(slots, candidates) of one sweep over every agent, by numpy."""
+    """(slots, candidates, rows, window rows) of one sweep over every
+    agent, by numpy."""
     dims = np.asarray(cfg.grid_spec.dims)
     rel = (pos - np.float32(0.0)) / np.float32(cfg.cell_size)
     cell = np.clip(np.floor(rel).astype(np.int64), 0, dims - 1)
     near = (np.abs(cell[:, None, :] - cell[None, :, :]) <= 1).all(-1)
     np.fill_diagonal(near, False)
     if mode == "unfused":
-        return 0, 0
+        return 0, 0, 0, 0
     if mode == "streamed":
-        return N * 9 * cfg.grid_spec.run_capacity, int(near.sum())
-    d = pos[None, :, :] - pos[:, None, :]
-    in_reach = near & (np.sum(d * d, axis=-1)
-                       <= np.square(np.float32(cfg.interaction_radius)))
-    return N * MAX_PAIRS, int(np.minimum(in_reach.sum(1), MAX_PAIRS).sum())
+        order = np.argsort((cell[:, 0] * dims[1] + cell[:, 1]) * dims[2]
+                           + cell[:, 2], kind="stable")
+        window_rows = _window_rows(cell[order], dims)
+        slots = 9 * (grid.WINDOW * window_rows
+                     + cfg.grid_spec.run_capacity * (N - window_rows))
+        return slots, int(near.sum()), N, window_rows
+    i, j = np.nonzero(near)
+    d = pos[j] - pos[i]
+    in_reach = (np.sum(d * d, axis=-1)
+                <= np.square(np.float32(cfg.interaction_radius)))
+    per_row = np.bincount(i[in_reach], minlength=N)
+    return (N * MAX_PAIRS, int(np.minimum(per_row, MAX_PAIRS).sum()), N, 0)
+
+
+def _counts(stats):
+    return tuple(int(stats[f]) for f in StepStats.WORK_FIELDS)
 
 
 @pytest.mark.parametrize("mode", ["streamed", "pairlist", "unfused"])
@@ -71,8 +118,23 @@ def test_sweep_counters_equal_a_plain_count(mode):
     stats = sim.step(sim.init_state(pos, diameter=dia, agent_type=types,
                                     extra_init=extra)).stats
     assert not stats.flags()                   # no z-run or list truncated
-    got = (int(stats.sweep_slots), int(stats.sweep_candidates))
-    assert got == _plain_count(pos, cfg, mode)
+    assert _counts(stats) == _plain_count(pos, cfg, mode)
+
+
+@pytest.mark.parametrize("clustered", [False, True],
+                         ids=["uniform", "clustered"])
+def test_sweep_window_rows_equal_a_plain_count(clustered):
+    pos, dia, types, extra = _population(7, clustered)
+    cfg = _cfg("streamed")
+    sim = Simulation(cfg, _behaviors())
+    stats = sim.step(sim.init_state(pos, diameter=dia, agent_type=types,
+                                    extra_init=extra)).stats
+    assert not stats.flags()
+    got = _counts(stats)
+    assert got == _plain_count(pos, cfg, "streamed")
+    # uniform: every tile on the window path; clustered: not those beside
+    # the dense slab
+    assert (got[3] == N) != clustered and got[3] > 0
 
 
 def test_ensemble_lanes_count_their_own_sweeps():
@@ -86,8 +148,8 @@ def test_ensemble_lanes_count_their_own_sweeps():
     stats = eng.step(st).stats
     want = np.array([_plain_count(_population(s)[0], cfg, "streamed")
                      for s in seeds])
-    got = np.stack([np.asarray(stats.sweep_slots),
-                    np.asarray(stats.sweep_candidates)], axis=1)
+    got = np.stack([np.asarray(stats[f]) for f in StepStats.WORK_FIELDS],
+                   axis=1)
     assert got.tolist() == want.tolist()
     # summed over lanes, as the benchmark reads a step's counters
     share = got[:, 1].sum() / got[:, 0].sum()

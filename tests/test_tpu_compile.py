@@ -5,7 +5,9 @@ described ``v5e:2x2`` topology without the chip. That catches what interpret
 mode cannot: primitives Mosaic does not lower (an in-kernel scatter did not)
 and operands that overflow fast memory (the scalar-prefetched column table,
 sized by pool capacity, once outgrew SMEM). Capacities: 65,536 and
-8,388,608, the oncology deployment's capacity at 1,048,576 seed agents.
+8,388,608, the oncology deployment's capacity at 1,048,576 seed agents. The
+SIR step compiles too, at 4,096 agents and at the epidemiology-sir cell's
+1,048,576, where the neighbor sweep's window loop must read no gather.
 
 The topology is described in a process of its own (``described``), never in
 a test worker: only one process at a time may load the TPU library, and once
@@ -71,9 +73,11 @@ def _k1(capacity, adhesion):
             compiled.memory_analysis().output_size_in_bytes)
 
 
-def _sir_step_text(scopes=True, counters=True) -> str:
-    """The SIR step at 4,096 agents compiled for the described chip, with
-    or without the engine's phase scopes and the sweep's work counters."""
+def _sir_step_text(scopes=True, counters=True, n=4096) -> str:
+    """The SIR step at ``n`` agents compiled for the described chip, with
+    or without the engine's phase scopes and the sweep's work counters; at
+    1,048,576 agents it is the epidemiology-sir cell's step (bench/configs:
+    a cube of side n^(1/3)·5 µm, query blocks of 4,096)."""
     import jax
     import jax.numpy as jnp
     from repro.core import EngineConfig, Simulation, grid
@@ -86,12 +90,15 @@ def _sir_step_text(scopes=True, counters=True) -> str:
         if not counters:
             zero = jnp.zeros((), jnp.int32)
             patches.enter_context(mock.patch.object(
-                grid, "fused_sweep_work", lambda *a, **k: (zero, zero)))
-        n, side = 4096, 80.0
+                grid, "fused_sweep_work",
+                lambda *a, **k: (jnp.zeros((), jnp.float32), zero, zero,
+                                 zero)))
+        side = max(80.0, n ** (1 / 3) * 5.0)
         sim = Simulation(
             EngineConfig(capacity=n, domain_lo=(0.0,) * 3,
                          domain_hi=(side,) * 3, interaction_radius=3.0,
-                         use_forces=False, query_chunk=1024),
+                         use_forces=False,
+                         query_chunk=1024 if n == 4096 else 4096),
             [RandomWalk(sigma=0.8),
              Infection(radius=3.0, beta=0.25, recovery_time=40)])
         state = jax.eval_shape(lambda: sim.init_state(
@@ -141,11 +148,12 @@ def test_phase_scopes_leave_the_v5e_program_unchanged(described):
     assert _without_metadata(unscoped) == _without_metadata(scoped)
 
 
-def _sweep_loop(text: str) -> str:
-    """The neighbor sweep's block loop: its condition and body and every
-    computation they call, in order, without metadata, with names numbered
-    by first use, so that a program that only adds work outside the loop
-    gives the same text."""
+def _sweep_loops(text: str) -> dict:
+    """The neighbor sweep's tile loops, by path (``window_path``,
+    ``gather_path``): each loop's condition and body and every computation
+    they call, in order, without metadata, with names numbered by first
+    use, so that a program that only adds work outside the loops gives the
+    same text."""
     comps, name = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
@@ -155,45 +163,60 @@ def _sweep_loop(text: str) -> str:
             comps[name].append(line)
             name = None if line == "}" else name
     loops = re.findall(r"condition=%(\S+), body=%(\S+), "
-                       r"metadata=\{op_name=\"[^\"]*neighbor_sweep/while\"",
+                       r"metadata=\{op_name=\"[^\"]*neighbor_sweep/(\w+)/while\"",
                        text)
-    assert len(loops) == 1, loops
-    order = []
+    paths = [path for _, _, path in loops]
+    assert sorted(paths) == ["gather_path", "window_path"], paths
+    out = {}
+    for cond, body, path in loops:
+        order = []
 
-    def visit(comp):
-        if comp not in order:
-            order.append(comp)
-            for line in comps[comp]:
-                for callee in re.findall(
-                        r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
-                        line):
-                    visit(callee)
+        def visit(comp):
+            if comp not in order:
+                order.append(comp)
+                for line in comps[comp]:
+                    for callee in re.findall(
+                            r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                            line):
+                        visit(callee)
 
-    for comp in loops[0]:
-        visit(comp)
-    lines = []
-    for comp in order:
-        # reads of the loop's tuple cost nothing, and their listed order
-        # varies: list each run of them by tuple index
-        for gte, run in itertools.groupby(
-                comps[comp], lambda line: " get-tuple-element(" in line):
-            run = list(run)
-            lines += sorted(run, key=lambda line: re.sub(
-                r"%[\w.\-]+", "%", line.split(" = ", 1)[1])) if gte else run
-    loop = _without_metadata("\n".join(lines))
-    loop = re.sub(r"\b(param_\d+|arg_tuple)\.\d+", r"\1", loop)
-    ids = {}
-    return re.sub(r"%[\w.\-]+",
-                  lambda m: ids.setdefault(m.group(0), f"%v{len(ids)}"), loop)
+        visit(cond)
+        visit(body)
+        lines = []
+        for comp in order:
+            # reads of the loop's tuple cost nothing, and their listed
+            # order varies: list each run of them by tuple index
+            for gte, run in itertools.groupby(
+                    comps[comp], lambda line: " get-tuple-element(" in line):
+                run = list(run)
+                lines += sorted(run, key=lambda line: re.sub(
+                    r"%[\w.\-]+", "%", line.split(" = ", 1)[1])) if gte else run
+        loop = _without_metadata("\n".join(lines))
+        loop = re.sub(r"\b(param_\d+|arg_tuple)\.\d+", r"\1", loop)
+        ids = {}
+        out[path] = re.sub(r"%[\w.\-]+", lambda m: ids.setdefault(
+            m.group(0), f"%v{len(ids)}"), loop)
+    return out
 
 
 def test_sweep_counters_leave_the_v5e_sweep_loop_unchanged(described):
     """The sweep's work counters are computed from the grid tables outside
-    its block loop: without them the chip's compiler emits the same loop,
+    its tile loops: without them the chip's compiler emits the same loops,
     scheduled the same way."""
     counted = described.submit(_sir_step_text).result()
     uncounted = described.submit(_sir_step_text, counters=False).result()
-    assert _sweep_loop(counted) == _sweep_loop(uncounted)
+    assert _sweep_loops(counted) == _sweep_loops(uncounted)
+
+
+def test_the_cells_window_path_reads_no_gather_on_v5e(described):
+    """The epidemiology-sir cell's step compiles for the described chip at
+    its capacity, 1,048,576 agents, and the sweep's window loop reads every
+    stencil column as a slice: no gather op in it, where the per-row
+    fallback loop gathers."""
+    loops = _sweep_loops(described.submit(_sir_step_text,
+                                          n=1_048_576).result())
+    assert " gather(" not in loops["window_path"]
+    assert " gather(" in loops["gather_path"]
 
 
 def test_the_tpu_library_stays_out_of_the_test_process(described):
