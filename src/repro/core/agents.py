@@ -145,8 +145,10 @@ def make_pool(capacity: int,
     """Allocate a pool of ``capacity`` slots; fill the first ``n_live`` from args.
 
     ``extra_specs`` maps channel name → (shape_suffix, dtype, fill_value) or an
-    (n_live, ...) array of initial values. ``policy`` narrows auxiliary channel
-    dtypes (DtypePolicy); positions keep ``dtype`` (float32) regardless.
+    (n_live, ...) array of initial values; a callable ``fill_value`` is given
+    the (capacity,) slot indices and returns each slot's value. ``policy``
+    narrows auxiliary channel dtypes (DtypePolicy); positions keep ``dtype``
+    (float32) regardless.
     """
     policy = policy or DtypePolicy()
     if position is not None:
@@ -172,8 +174,15 @@ def make_pool(capacity: int,
     for name, spec in (extra_specs or {}).items():
         if isinstance(spec, tuple):
             shape_suffix, dt, fill = spec
-            extra[name] = jnp.full((capacity, *shape_suffix), fill,
-                                   dtype=policy.extra_dtype(dt))
+            dt = policy.extra_dtype(dt)
+            if callable(fill):
+                values = fill(jnp.arange(capacity, dtype=jnp.int32))
+                extra[name] = jnp.broadcast_to(
+                    jnp.reshape(values, (capacity,) + (1,) * len(shape_suffix)),
+                    (capacity, *shape_suffix)).astype(dt)
+            else:
+                extra[name] = jnp.full((capacity, *shape_suffix), fill,
+                                       dtype=dt)
         else:  # array of initial live values
             arr = jnp.asarray(spec)
             dt = policy.extra_dtype(arr.dtype)

@@ -22,6 +22,7 @@ array draws do not have this property.
 
 The catalogue below covers the paper's five benchmark simulations (Table 1):
   GrowDivide          cell proliferation / oncology (create agents)
+  Lineage             oncology (clone labels that daughters inherit)
   RandomWalk          epidemiology / oncology (agents move randomly)
   Infection+Recovery  epidemiology (SIR over spatial neighbors)
   Chemotaxis          cell clustering (move along substance gradient)
@@ -103,6 +104,8 @@ class GrowDivide(Behavior):
 
     Division: mother shrinks to volume/2, daughter (staged birth) placed at a
     random direction at center distance = mother radius (BioDynaMo CellDivision).
+    The daughter copies the mother's type and behavior channels (``extra.*``),
+    as BioDynaMo's division copies the mother's attributes.
     """
 
     name = "grow_divide"
@@ -133,12 +136,29 @@ class GrowDivide(Behavior):
         direction /= jnp.sqrt(
             jnp.sum(direction * direction, -1, keepdims=True) + 1e-12)
         d_pos = pool.position + direction * (mother_dia * 0.5)[:, None]
+        inherited = {"extra." + k: v for k, v in pool.extra.items()}
         return BehaviorEffects(
             set_channels={"diameter": mother_dia},
-            birth_channels={"position": d_pos, "diameter": mother_dia,
+            birth_channels={**inherited, "position": d_pos,
+                            "diameter": mother_dia,
                             "agent_type": pool.agent_type},
             birth_valid=divide,
         )
+
+
+class Lineage(Behavior):
+    """Clone labels for lineage tracing: every seeded agent carries its own
+    label (its seed index + 1, in channel ``extra.clone``), and a daughter
+    inherits her mother's (GrowDivide). The label never acts on the
+    dynamics; it says which seed cell each agent descends from."""
+
+    name = "lineage"
+
+    def extra_specs(self):
+        return {"clone": ((), jnp.int32, lambda slot: slot + 1)}
+
+    def __call__(self, ctx, pool: AgentPool, rng: jax.Array) -> BehaviorEffects:
+        return BehaviorEffects()
 
 
 class RandomWalk(Behavior):

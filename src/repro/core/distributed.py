@@ -51,8 +51,8 @@ from . import compaction, diffusion as diff_mod, grid as grid_mod
 from .agents import AgentPool, make_pool, pool_from_channels
 from .behaviors import Behavior
 from .engine import (CapacityExhausted, EngineConfig, LadderConfig,
-                     LadderDriverBase, next_rung, make_iteration_core,
-                     stage_pool)
+                     LadderDriverBase, colmap_rung, next_rung,
+                     make_iteration_core, stage_pool)
 from .stats import StepStats
 
 OWNED = "owned"          # bool extra channel: local agent (True) vs ghost
@@ -586,6 +586,12 @@ class DistributedSimulation:
                         "box_overflow": (
                             "grid run overflow on a shard; raise "
                             "EngineConfig.max_per_run / max_per_box"),
+                        "colmap_overflow": (
+                            f"K1 column-map overflow on a shard (per-shard "
+                            f"demand {np.asarray(s.colmap_demand).tolist()} "
+                            f"column blocks > the map's width "
+                            f"{self.dcfg.engine.colmap_capacity}); raise "
+                            f"EngineConfig.colmap_width"),
                         "pair_overflow": (
                             f"pair-list overflow on a shard (an agent has "
                             f"more in-range(+skin) candidates than "
@@ -603,8 +609,8 @@ class DistributedSimulation:
                     # report in severity order, not dict order
                     for f in ("halo_overflow", "thin_slab",
                               "migrate_overflow", "in_flight",
-                              "box_overflow", "pair_overflow",
-                              "birth_overflow"):
+                              "box_overflow", "colmap_overflow",
+                              "pair_overflow", "birth_overflow"):
                         if f in flags:
                             raise RuntimeError(
                                 f"iteration {i}: {remediation[f]}")
@@ -692,6 +698,10 @@ class DistributedCapacityLadder(LadderDriverBase):
                 cur = eng.grid_spec.run_capacity
                 eng = dataclasses.replace(eng, max_per_run=next_rung(
                     cur, demand, lad.growth_factor))
+        if tot("colmap_overflow"):
+            eng = dataclasses.replace(eng, colmap_width=colmap_rung(
+                eng, int(np.asarray(jnp.max(stats["colmap_demand"]))),
+                lad.growth_factor))
         if tot("pair_overflow"):
             # agreed global rung: one max_pairs for every shard, sized off
             # the worst per-shard demand
@@ -742,6 +752,8 @@ class DistributedCapacityLadder(LadderDriverBase):
              for f in ("local_capacity", "halo_capacity", "migrate_capacity")]
             + [(f, getattr(self.dcfg.engine, f), getattr(new_d.engine, f))
                for f in ("max_per_box", "max_per_run")]
+            + [("colmap_width", self.dcfg.engine.colmap_capacity,
+                new_d.engine.colmap_capacity)]
             + ([("max_pairs", self.dcfg.engine.pairlist.max_pairs,
                  new_d.engine.pairlist.max_pairs)]
                if (new_d.engine.pairlist is not None

@@ -77,6 +77,10 @@ class EngineConfig:
                                            # interpret mode off-TPU, native on TPU)
     max_per_box: int = 16
     max_per_run: Optional[int] = None      # gather width per 3-box z-run (None → 3·K)
+    colmap_width: Optional[int] = None     # K1's column-map width: column
+                                           # blocks a 128-row block may
+                                           # visit (None → derived from the
+                                           # run capacity; colmap_capacity)
     query_chunk: int = 2048
     adhesion: Optional[Tuple[Tuple[float, ...], ...]] = None  # type adhesion matrix
     force: force_mod.ForceParams = dataclasses.field(default_factory=force_mod.ForceParams)
@@ -155,6 +159,16 @@ class EngineConfig:
         every candidate within r + skin for grid.build_pairlist)."""
         skin = self.pairlist.skin if self.pairlist is not None else 0.0
         return self.interaction_radius + max(self.rebuild.cell_slack, skin)
+
+    @property
+    def colmap_capacity(self) -> int:
+        """K1's column-map width: ``colmap_width``, or by default one
+        derived from the grid's run capacity
+        (kernels/ops.default_colmap_width)."""
+        if self.colmap_width is not None:
+            return self.colmap_width
+        from ..kernels import ops as kops
+        return kops.default_colmap_width(self.grid_spec.run_capacity)
 
     @property
     def grid_spec(self) -> grid_mod.GridSpec:
@@ -427,11 +441,15 @@ def check_kernel_footprints(cfg: EngineConfig, behaviors: Sequence[Behavior],
 # Scopes are metadata: they change no op of the compiled program.
 PHASES = ("grid_build", "pairlist_build", "statics", "diffusion",
           grid_mod.SWEEP_SCOPE, "behaviors", "health", "commit")
+# Scopes nested inside those phases, on the same rule: K1's column-map build
+# and kernel (inside the sweep's; kernels/ops.py), and the commit's three
+# parts.
+SUBPHASES = ("colmap_build", "k1", "deaths", "compaction", "births")
 
 
 def _phase(name: str):
-    if name not in PHASES:
-        raise ValueError(f"{name!r} is not one of {PHASES}")
+    if name not in PHASES + SUBPHASES:
+        raise ValueError(f"{name!r} is not one of {PHASES + SUBPHASES}")
     return jax.named_scope(name)
 
 
@@ -656,7 +674,8 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             else:
                 active = owned_alive
         nbr_results: Dict[str, Dict[str, jnp.ndarray]] = {}
-        sweep_work = {}                   # StepStats.WORK_FIELDS, if swept
+        work = {}                         # StepStats.WORK_FIELDS of what ran
+        colmap = None                     # K1's ColmapWork, if K1 ran
         if fused:
             kernels = []
             if cfg.use_forces:
@@ -673,16 +692,14 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                     # remaining kernels share one pruned XLA sweep over the
                     # same grid tables (kernels/ops.fused_resident_sweep)
                     from ..kernels import ops as kops
-                    nbr_results, ovf = kops.fused_resident_sweep(
+                    nbr_results, colmap = kops.fused_resident_sweep(
                         spec, grid_env, channels_full, kernels,
                         default_mask=owned_alive, origin=origin,
                         box_size=box_size, k_rep=cfg.force.k_rep,
                         adhesion=cfg.adhesion,
                         adhesion_band=cfg.force.adhesion_band,
                         chunk=cfg.query_chunk, pvary_axes=pvary_axes,
-                        pairs=pairs)
-                    box_overflow = jnp.maximum(box_overflow,
-                                               ovf.astype(jnp.int32))
+                        maxb=cfg.colmap_capacity, pairs=pairs)
                     xla_kernels = [k for k in kernels if k.name != "force"]
                 else:
                     nbr_results = grid_mod.resident_apply_fused(
@@ -691,13 +708,11 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                         pvary_axes=pvary_axes, pairs=pairs)
                     xla_kernels = kernels
                 # the XLA sweep's work, counted outside its block loop
-                sweep_work = dict(zip(StepStats.WORK_FIELDS,
-                                      grid_mod.fused_sweep_work(
-                                          spec, grid_env, xla_kernels,
-                                          owned_alive,
-                                          channels_full["position"],
-                                          chunk=cfg.query_chunk,
-                                          pairs=pairs)))
+                work = dict(zip(StepStats.SWEEP_FIELDS,
+                                grid_mod.fused_sweep_work(
+                                    spec, grid_env, xla_kernels,
+                                    owned_alive, channels_full["position"],
+                                    chunk=cfg.query_chunk, pairs=pairs)))
                 # the later phases read the pool after the sweep: what they
                 # write (the walk's positions, say) is then not live across
                 # the build and the sweep, which sets the step's peak memory
@@ -714,17 +729,15 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                 # step's grid tables directly (no sort/unsort) and skips
                 # fully-static row blocks (kernels/ops.py)
                 from ..kernels import ops as kops
-                f, nnz, ovf = kops.collision_force_resident(
+                f, nnz, colmap = kops.collision_force_resident(
                     pool.position, pool.diameter, pool.agent_type,
                     pool.alive, active, grid_env.starts, grid_env.counts,
                     origin, box_size,
                     dims=spec.dims, k_rep=cfg.force.k_rep,
                     adhesion=cfg.adhesion,
-                    adhesion_band=cfg.force.adhesion_band)
-                # column-map overflow means possibly-missed pairs: surface
-                # it through the same never-silent contract (DESIGN.md §4.2)
-                box_overflow = jnp.maximum(box_overflow,
-                                           ovf.astype(jnp.int32))
+                    adhesion_band=cfg.force.adhesion_band,
+                    maxb=cfg.colmap_capacity,
+                    span=kops.colmap_span(spec.run_capacity))
                 res = {"force": f, "force_nnz": nnz}
             else:
                 res = nbr_apply(fpair, force_mod.FORCE_OUT_SPECS,
@@ -804,29 +817,35 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
 
         # ---------------- post standalone ops: commit ----------------
         with _phase("commit"):
-            # ghosts are the neighbor shard's to kill — only owned deaths commit
-            death_mask &= owned_of(pool)
-            deaths = jnp.sum((death_mask & pool.alive).astype(jnp.int32))
-            pool = dataclasses.replace(pool, alive=pool.alive & ~death_mask)
-            # n_active = force-computed agents still alive at iteration end
-            # (counting at force time could exceed n_live after deaths)
-            n_active = (jnp.sum((active & pool.alive).astype(jnp.int32))
-                        if active is not None
-                        else jnp.sum(owned_of(pool).astype(jnp.int32)))
-            pool = jax.lax.cond(deaths > 0, compaction.compact,
-                                lambda p: p, pool)
+            with _phase("deaths"):
+                # ghosts are the neighbor shard's to kill — only owned
+                # deaths commit
+                death_mask &= owned_of(pool)
+                deaths = jnp.sum((death_mask & pool.alive).astype(jnp.int32))
+                pool = dataclasses.replace(pool,
+                                           alive=pool.alive & ~death_mask)
+                # n_active = force-computed agents still alive at iteration
+                # end (counting at force time could exceed n_live after
+                # deaths)
+                n_active = (jnp.sum((active & pool.alive).astype(jnp.int32))
+                            if active is not None
+                            else jnp.sum(owned_of(pool).astype(jnp.int32)))
+            with _phase("compaction"):
+                pool = jax.lax.cond(deaths > 0, compaction.compact,
+                                    lambda p: p, pool)
 
             births = jnp.zeros((), jnp.int32)
             birth_overflow = jnp.zeros((), jnp.int32)
-            for q, valid in birth_queues:
-                if owned_channel is not None:
-                    # newborns are committed — and later migrated if needed — by
-                    # the shard that staged them
-                    q = dict(q)
-                    q["extra." + owned_channel] = jnp.ones_like(valid)
-                birth_overflow += compaction.birth_overflow(pool, valid)
-                births += jnp.sum(valid.astype(jnp.int32))
-                pool = compaction.commit_births(pool, q, valid, it)
+            with _phase("births"):
+                for q, valid in birth_queues:
+                    if owned_channel is not None:
+                        # newborns are committed — and later migrated if
+                        # needed — by the shard that staged them
+                        q = dict(q)
+                        q["extra." + owned_channel] = jnp.ones_like(valid)
+                    birth_overflow += compaction.birth_overflow(pool, valid)
+                    births += jnp.sum(valid.astype(jnp.int32))
+                    pool = compaction.commit_births(pool, q, valid, it)
 
             if use_cache:
                 # deaths ran the compaction permutation and births appended live
@@ -844,6 +863,14 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
                        if pl is not None else {}))
 
             n_live_end = jnp.sum(owned_of(pool).astype(jnp.int32))
+        if colmap is not None:
+            # a column block missing from K1's map means possibly-missed
+            # pairs: the never-silent contract, with the width's own flag
+            # and demand (DESIGN.md §4.2)
+            work.update(
+                colmap_overflow=colmap.overflow.astype(jnp.int32),
+                colmap_demand=colmap.demand, k1_tiles=colmap.tiles,
+                k1_live_rows=colmap.live_rows)
         stats = dataclasses.replace(
             stats, n_live=n_live_end,
             n_active=n_active, births=births, deaths=deaths,
@@ -853,7 +880,7 @@ def make_iteration_core(cfg: EngineConfig, behaviors: Sequence[Behavior],
             # provenance: the capacity rung target)
             capacity_demand=n_live_end + birth_overflow,
             pair_overflow=pair_overflow, pair_demand=pair_demand,
-            rebuilds=rebuilt, health=health, **sweep_work)
+            rebuilds=rebuilt, health=health, **work)
         return pool, conc, rng, stats, env
 
     return core
@@ -938,10 +965,12 @@ class Simulation:
             callback: Callable[[int, EngineState], None] | None = None,
             check_overflow: bool = False) -> EngineState:
         """Run ``n_iterations``. With ``check_overflow`` the host checks the
-        box/birth overflow flags each iteration and raises — the engine never
+        overflow flags each iteration and raises — the engine never
         silently drops interactions (DESIGN.md §4.2 fallback contract); callers
-        respond by raising ``max_per_box`` / ``capacity`` (a recompile, mirroring
-        BioDynaMo's dynamic grid growth)."""
+        respond by raising the capacity the message names: ``max_per_run`` /
+        ``max_per_box``, ``capacity``, ``pairlist.max_pairs`` or
+        ``colmap_width`` (a recompile, mirroring BioDynaMo's dynamic grid
+        growth)."""
         for i in range(n_iterations):
             # host spans on the profiler's clock (free unless it traces)
             with jax.profiler.TraceAnnotation("sim.step", iteration=i):
@@ -972,6 +1001,13 @@ class Simulation:
                         f"iteration {i}: pair-list overflow (an agent has > "
                         f"{self.config.pairlist.max_pairs} in-range(+skin) "
                         f"candidates); raise PairListConfig.max_pairs")
+                if "colmap_overflow" in flags:
+                    raise RuntimeError(
+                        f"iteration {i}: K1 column-map overflow (a 128-row "
+                        f"block needs {int(state.stats.colmap_demand)} column "
+                        f"blocks > the map's width "
+                        f"{self.config.colmap_capacity}); raise "
+                        f"EngineConfig.colmap_width")
             if callback is not None:
                 callback(i, state)
         return state
@@ -1045,6 +1081,22 @@ def next_rung(old: int, demand: int, factor: float, round_to: int = 1) -> int:
     while new < demand:
         new = int(math.ceil(new * factor))
     return -(-new // round_to) * round_to
+
+
+def colmap_rung(cfg: EngineConfig, demand: int, factor: float) -> int:
+    """K1's next column-map width for ``demand`` column blocks: the next
+    geometric rung, held to one SMEM slice (SMEM_TABLE_ENTRIES entries: a
+    launch then covers one row block). A demand beyond that raises
+    ``CapacityExhausted``; none can arise in a pool of at most 2**22 slots,
+    which has no more column blocks than that."""
+    from ..kernels import collision_force as k1
+    if demand > k1.SMEM_TABLE_ENTRIES:
+        raise CapacityExhausted(
+            f"column-map demand {demand} exceeds one SMEM slice "
+            f"({k1.SMEM_TABLE_ENTRIES} entries)", demand=demand,
+            rung=demand, max_capacity=k1.SMEM_TABLE_ENTRIES)
+    return min(next_rung(cfg.colmap_capacity, demand, factor),
+               k1.SMEM_TABLE_ENTRIES)
 
 
 class LadderDriverBase:
@@ -1140,6 +1192,11 @@ class CapacityLadder(LadderDriverBase):
                         ``max_per_box``    (hash grid bucket width)
       pair_overflow   → ``pairlist.max_pairs`` (Verlet list row width;
                         rung target: pair_demand)
+      colmap_overflow → ``colmap_width``   (K1's column-map width; rung
+                        target: colmap_demand, at most one SMEM slice,
+                        colmap_rung), where the demand passed the width;
+                        else a z-run outran K1's span, which box_overflow
+                        flags too and ``max_per_run`` clears
 
     Growth events are recorded in ``self.rungs`` and recompiles counted in
     ``self.recompiles`` (benchmarks/capacity.py reports both).
@@ -1183,6 +1240,16 @@ class CapacityLadder(LadderDriverBase):
                 cur = cfg.grid_spec.run_capacity
                 changes["max_per_run"] = next_rung(
                     cur, demand, lad.growth_factor)
+        if int(stats["colmap_overflow"]):
+            if int(stats["colmap_demand"]) > cfg.colmap_capacity:
+                changes["colmap_width"] = colmap_rung(
+                    cfg, int(stats["colmap_demand"]), lad.growth_factor)
+            elif "max_per_run" not in changes:
+                # the map fit its width, so a z-run outran K1's span, which
+                # only a run over the run capacity can (ops.colmap_span)
+                raise RuntimeError(
+                    "K1 column-map span overflow without a grid run "
+                    "overflow: the span no longer follows the run capacity")
         if int(stats["birth_overflow"]):
             demand = int(stats["capacity_demand"])
             new_cap = next_rung(cfg.capacity, demand, lad.growth_factor,
@@ -1202,6 +1269,8 @@ class CapacityLadder(LadderDriverBase):
               iteration: int) -> EngineState:
         rungs = [(f, getattr(self.config, f), getattr(new_cfg, f))
                  for f in ("capacity", "max_per_box", "max_per_run")]
+        rungs.append(("colmap_width", self.config.colmap_capacity,
+                      new_cfg.colmap_capacity))
         if new_cfg.pairlist is not None and self.config.pairlist is not None:
             rungs.append(("max_pairs", self.config.pairlist.max_pairs,
                           new_cfg.pairlist.max_pairs))

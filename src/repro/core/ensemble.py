@@ -49,7 +49,8 @@ from .agents import AgentPool
 from .behaviors import Behavior
 from .engine import (CapacityExhausted, EngineConfig, EngineState,
                      LadderConfig, LadderDriverBase, ScenarioParams,
-                     Simulation, make_iteration_core, next_rung)
+                     Simulation, colmap_rung, make_iteration_core,
+                     next_rung)
 from .stats import StepStats
 
 
@@ -320,6 +321,9 @@ class EnsembleCapacityLadder(LadderDriverBase):
             else:
                 changes["max_per_run"] = next_rung(
                     cfg.grid_spec.run_capacity, demand, lad.growth_factor)
+        if tot("colmap_overflow"):
+            changes["colmap_width"] = colmap_rung(
+                cfg, peak("colmap_demand"), lad.growth_factor)
         if tot("birth_overflow"):
             demand = peak("capacity_demand")
             new_cap = next_rung(cfg.capacity, demand, lad.growth_factor,
@@ -339,6 +343,8 @@ class EnsembleCapacityLadder(LadderDriverBase):
               iteration: int) -> EnsembleState:
         rungs = [(f, getattr(self.config, f), getattr(new_cfg, f))
                  for f in ("capacity", "max_per_box", "max_per_run")]
+        rungs.append(("colmap_width", self.config.colmap_capacity,
+                      new_cfg.colmap_capacity))
         if new_cfg.pairlist is not None and self.config.pairlist is not None:
             rungs.append(("max_pairs", self.config.pairlist.max_pairs,
                           new_cfg.pairlist.max_pairs))

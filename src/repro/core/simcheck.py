@@ -69,6 +69,7 @@ def _engine_knobs(cfg: EngineConfig) -> Dict:
     return {"capacity": cfg.capacity,
             "max_per_box": cfg.max_per_box,
             "max_per_run": cfg.max_per_run,
+            "colmap_width": cfg.colmap_width,
             "dt": cfg.dt,
             "fused_sweep": cfg.fused_sweep,
             "force_impl": cfg.force_impl,
@@ -90,6 +91,8 @@ def _apply_engine_knobs(cfg: EngineConfig, knobs: Dict,
         raise ValueError(f"apply_knobs must be 'all' or 'rungs', got {mode!r}")
     changes: Dict[str, Any] = {k: knobs[k] for k in
                                ("capacity", "max_per_box", "max_per_run")}
+    # manifests written before the column map had its own rung lack it
+    changes["colmap_width"] = knobs.get("colmap_width", cfg.colmap_width)
     if mode == "all":
         changes.update(dt=knobs["dt"], fused_sweep=knobs["fused_sweep"],
                        force_impl=knobs["force_impl"],

@@ -31,8 +31,8 @@ class StepStats:
     n_active:         force-computed agents still alive at iteration end
                       (§5 static skipping makes this < n_live)
     births / deaths:  agents added / removed this iteration (§3.2)
-    box_overflow:     grid run / hash bucket / Pallas column-map capacity
-                      exceeded — possibly-missed neighbor pairs (§4.2)
+    box_overflow:     grid run / hash bucket capacity exceeded —
+                      possibly-missed neighbor pairs (§4.2)
     birth_overflow:   staged newborns that did not fit in capacity
     halo_overflow:    ghost-band agents that did not fit the halo buffer
                       (distributed only; §7)
@@ -93,6 +93,17 @@ class StepStats:
                       (grid.fused_sweep_work), and are 0 on paths that
                       run no fused XLA sweep (non-fused environments, K1
                       alone)
+    colmap_overflow:  a K1 row block needed more column blocks than the
+                      column map's width (EngineConfig.colmap_width), or a
+                      merged stencil run more than the map's span: missed
+                      column blocks mean possibly-missed pairs (§4.2). The
+                      ladder grows the width from colmap_demand
+    colmap_demand:    which-capacity provenance for colmap_overflow: the
+                      most column blocks any K1 row block needed this step
+                      (0 off the K1 path)
+    k1_tiles:         (row block, column block) entries K1 visited: each
+                      is one 128×128 tile of pairs
+    k1_live_rows:     rows whose own force K1 computed
     """
 
     n_live: jnp.ndarray
@@ -115,21 +126,29 @@ class StepStats:
     sweep_candidates: jnp.ndarray
     sweep_rows: jnp.ndarray
     sweep_window_rows: jnp.ndarray
+    colmap_overflow: jnp.ndarray
+    colmap_demand: jnp.ndarray
+    k1_tiles: jnp.ndarray
+    k1_live_rows: jnp.ndarray
 
     FIELDS = ("n_live", "n_active", "births", "deaths", "box_overflow",
               "birth_overflow", "halo_overflow", "migrate_overflow",
               "in_flight", "thin_slab", "box_demand", "capacity_demand",
               "pair_overflow", "pair_demand", "rebuilds", "health",
               "sweep_slots", "sweep_candidates", "sweep_rows",
-              "sweep_window_rows")
+              "sweep_window_rows", "colmap_overflow", "colmap_demand",
+              "k1_tiles", "k1_live_rows")
 
     # the §4.2 never-silent-loss flags (demands and health are not overflow)
     OVERFLOW_FIELDS = ("box_overflow", "birth_overflow", "halo_overflow",
                        "migrate_overflow", "in_flight", "thin_slab",
-                       "pair_overflow")
-    # the sweep's work: counts, so sums over shards or lanes stay counts
-    WORK_FIELDS = ("sweep_slots", "sweep_candidates", "sweep_rows",
-                   "sweep_window_rows")
+                       "pair_overflow", "colmap_overflow")
+    # the work of the fused XLA sweep and of K1: counts, so sums over shards
+    # or lanes stay counts (colmap_demand, a largest count, sums to a bound)
+    SWEEP_FIELDS = ("sweep_slots", "sweep_candidates", "sweep_rows",
+                    "sweep_window_rows")
+    K1_FIELDS = ("colmap_demand", "k1_tiles", "k1_live_rows")
+    WORK_FIELDS = SWEEP_FIELDS + K1_FIELDS
 
     @classmethod
     def zeros(cls, shape: tuple = ()) -> "StepStats":

@@ -15,6 +15,9 @@ benchmark's metric reader or a dashboard polled between runs:
     shards or lanes, and ``compile_seconds()`` as it stood then, which
     leaves out what the process compiled after its simulation stepped;
     None before the first such step.
+  * ``step_totals()``: the same work counters summed over every step whose
+    flags ``Simulation.run`` checked in this process, with the number of
+    those steps; None before the first.
 
 Importing the module registers the listener; ``repro.core.engine`` imports
 it, so every compile of a simulation is seen.
@@ -38,7 +41,13 @@ class StepRecord(NamedTuple):
     compile_s: float
 
 
+class StepTotals(NamedTuple):
+    steps: int
+    counts: Dict[str, int]
+
+
 _last_step: Optional[StepRecord] = None
+_totals: Optional[StepTotals] = None
 
 
 def _on_duration(event: str, duration: float, **_) -> None:
@@ -63,9 +72,16 @@ def compile_seconds() -> float:
 
 
 def record_step(counts: Mapping[str, int]) -> None:
-    global _last_step
+    global _last_step, _totals
     _last_step = StepRecord(dict(counts), compile_seconds())
+    steps, total = _totals or (0, {})
+    _totals = StepTotals(steps + 1, {k: total.get(k, 0) + v
+                                     for k, v in counts.items()})
 
 
 def last_step() -> Optional[StepRecord]:
     return _last_step
+
+
+def step_totals() -> Optional[StepTotals]:
+    return _totals

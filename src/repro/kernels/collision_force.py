@@ -128,8 +128,12 @@ def rows_per_call(maxb: int) -> int:
     """Row blocks one ``pallas_call`` covers: its slice of the column table
     (rows × maxb int32) is the scalar-prefetched operand and must fit SMEM
     (1 MiB on v5e) at any capacity, so the table is cut into slices of at
-    most ``SMEM_TABLE_ENTRIES`` entries."""
-    return max(1, SMEM_TABLE_ENTRIES // maxb)
+    most ``SMEM_TABLE_ENTRIES`` entries. A width beyond that (one row block
+    alone would not fit) is refused."""
+    if not 1 <= maxb <= SMEM_TABLE_ENTRIES:
+        raise ValueError(f"column-map width {maxb} outside [1, "
+                         f"{SMEM_TABLE_ENTRIES}] (SMEM_TABLE_ENTRIES)")
+    return SMEM_TABLE_ENTRIES // maxb
 
 
 def padded_size(n: int, maxb: int) -> int:

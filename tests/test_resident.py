@@ -76,7 +76,7 @@ def test_resident_pallas_matches_streaming(rng):
         rpool.position, rpool.diameter, rpool.agent_type, rpool.alive,
         active, grid.starts, grid.counts, jnp.zeros(3), jnp.asarray(2.5),
         dims=spec.dims, k_rep=2.0, adhesion=None, adhesion_band=0.4)
-    assert not bool(ovf)
+    assert not bool(ovf.overflow)
 
     res = G.resident_apply(spec, grid, ch, active, pair, OUT_SPECS)
     np.testing.assert_allclose(np.asarray(f_k1), np.asarray(res["force"]),

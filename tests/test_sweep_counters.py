@@ -107,7 +107,7 @@ def _plain_count(pos, cfg, mode):
 
 
 def _counts(stats):
-    return tuple(int(stats[f]) for f in StepStats.WORK_FIELDS)
+    return tuple(int(stats[f]) for f in StepStats.SWEEP_FIELDS)
 
 
 @pytest.mark.parametrize("mode", ["streamed", "pairlist", "unfused"])
@@ -148,7 +148,7 @@ def test_ensemble_lanes_count_their_own_sweeps():
     stats = eng.step(st).stats
     want = np.array([_plain_count(_population(s)[0], cfg, "streamed")
                      for s in seeds])
-    got = np.stack([np.asarray(stats[f]) for f in StepStats.WORK_FIELDS],
+    got = np.stack([np.asarray(stats[f]) for f in StepStats.SWEEP_FIELDS],
                    axis=1)
     assert got.tolist() == want.tolist()
     # summed over lanes, as the benchmark reads a step's counters

@@ -17,6 +17,7 @@ def fresh(monkeypatch):
     monkeypatch.setattr(telemetry, "_compiles", [])
     monkeypatch.setattr(telemetry, "_compile_s", 0.0)
     monkeypatch.setattr(telemetry, "_last_step", None)
+    monkeypatch.setattr(telemetry, "_totals", None)
     return telemetry
 
 
@@ -58,3 +59,27 @@ def test_run_records_the_last_checked_step(fresh):
                            for f in StepStats.WORK_FIELDS}
     assert step.counts["sweep_slots"] > step.counts["sweep_candidates"] > 0
     assert 0 < step.compile_s <= fresh.compile_seconds()
+
+
+def test_run_sums_the_checked_steps(fresh):
+    """``step_totals`` adds up the work counters of every checked step."""
+    n, side = 256, 24.0
+    rng = np.random.default_rng(4)
+    sim = Simulation(
+        EngineConfig(capacity=n, domain_lo=(0.0,) * 3,
+                     domain_hi=(side,) * 3, interaction_radius=3.0,
+                     use_forces=False, query_chunk=128),
+        [Infection(radius=3.0, beta=0.5, recovery_time=8)])
+    types = np.where(np.arange(n) < n // 8, INFECTED, 0).astype(np.int32)
+    s0 = sim.init_state(rng.uniform(0.5, side - 0.5, (n, 3)), agent_type=types,
+                        extra_init={"infect_timer": np.full(n, 8, np.int32)})
+    assert fresh.step_totals() is None
+    seen = []
+    sim.run(s0, 3, check_overflow=True,
+            callback=lambda i, st: seen.append(
+                {f: int(st.stats[f]) for f in StepStats.WORK_FIELDS}))
+    totals = fresh.step_totals()
+    assert totals.steps == 3
+    assert totals.counts == {f: sum(c[f] for c in seen)
+                             for f in StepStats.WORK_FIELDS}
+    assert fresh.last_step().counts == seen[-1]

@@ -7,7 +7,8 @@ and operands that overflow fast memory (the scalar-prefetched column table,
 sized by pool capacity, once outgrew SMEM). Capacities: 65,536 and
 8,388,608, the oncology deployment's capacity at 1,048,576 seed agents. The
 SIR step compiles too, at 4,096 agents and at the epidemiology-sir cell's
-1,048,576, where the neighbor sweep's window loop must read no gather.
+1,048,576, where the neighbor sweep's window loop must read no gather, and
+so does the oncology-growth cell's step at its 2,097,152 slots.
 
 The topology is described in a process of its own (``described``), never in
 a test worker: only one process at a time may load the TPU library, and once
@@ -54,16 +55,16 @@ def _why_not():
     return _one_chip if isinstance(_one_chip, str) else None
 
 
-def _k1(capacity, adhesion):
+def _k1(capacity, adhesion, maxb=MAXB):
     """(padded size, custom call present, output bytes) of K1 compiled for
-    the described chip."""
+    the described chip, its column map ``maxb`` wide."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import collision_force as k1
 
-    n_pad = k1.padded_size(capacity, MAXB)
+    n_pad = k1.padded_size(capacity, maxb)
     data = jax.ShapeDtypeStruct((8, n_pad), jnp.float32, sharding=_one_chip)
-    cols = jax.ShapeDtypeStruct((n_pad // k1.BLOCK, MAXB), jnp.int32,
+    cols = jax.ShapeDtypeStruct((n_pad // k1.BLOCK, maxb), jnp.int32,
                                 sharding=_one_chip)
     step = jax.jit(lambda d, c: k1.collision_force_kernel(
         d, c, k_rep=2.0, adhesion=adhesion, adhesion_band=0.4,
@@ -109,6 +110,32 @@ def _sir_step_text(scopes=True, counters=True, n=4096) -> str:
         return sim._step_fn.lower(state).compile().as_text()
 
 
+def _oncology_step_text(agents=1_048_576) -> str:
+    """The oncology-growth cell's step (bench/configs/oncology.json: K1
+    forces at the pinned column-map width, growth, division, death) at
+    ``agents`` seeds, twice that many slots, compiled for the described
+    chip."""
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+    from bench import deploy
+
+    config = json.loads((Path(__file__).resolve().parent.parent / "bench"
+                         / "configs" / "oncology.json").read_text())
+    dep = deploy.deployment(config, agents=agents)
+    sim = dep.simulation()
+    state = jax.eval_shape(lambda: sim.init_state(
+        jnp.zeros((agents, 3), jnp.float32), jnp.ones((agents,), jnp.float32)))
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=_one_chip),
+        state)
+    # this process's backend is the CPU, where K1 would take interpret mode
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return sim._step_fn.lower(state).compile().as_text()
+
+
 # -- the tests -----------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -130,6 +157,18 @@ def test_k1_compiles_for_v5e(described, capacity, adhesion):
     n_pad, custom_call, out_bytes = described.submit(
         _k1, capacity, adhesion).result()
     assert custom_call
+    assert out_bytes == 8 * n_pad * 4
+
+
+def test_k1_compiles_for_v5e_at_the_widest_column_map(described):
+    """At the widest column map the capacity ladder reaches (one SMEM slice
+    per row block) and a pool of 2**22 slots, whose column blocks it can
+    all hold, every launch's table slice still fits."""
+    from repro.kernels import collision_force as k1
+    width = k1.SMEM_TABLE_ENTRIES
+    n_pad, custom_call, out_bytes = described.submit(
+        _k1, 1 << 22, None, width).result()
+    assert custom_call and n_pad == 1 << 22
     assert out_bytes == 8 * n_pad * 4
 
 
@@ -217,6 +256,27 @@ def test_the_cells_window_path_reads_no_gather_on_v5e(described):
                                           n=1_048_576).result())
     assert " gather(" not in loops["window_path"]
     assert " gather(" in loops["gather_path"]
+
+
+def test_the_oncology_cells_step_compiles_for_v5e(described):
+    """The oncology-growth cell's step compiles for the described chip at
+    its capacity, 2,097,152 slots, with K1 at the pinned column-map width:
+    its launches sit in the ``k1`` scope, inside the sweep's, and no
+    scatter, sort or gather has lost its ``op_name`` (the column map's
+    per-row-block scatter once did, and its time read as unscoped)."""
+    from bench import trace
+    text = described.submit(_oncology_step_text).result()
+    labels = trace.hlo_labels(text)
+    assert not [name for name, kinds in trace.hlo_kinds(text).items()
+                if kinds & {"scatter", "sort", "gather"}
+                and name not in labels]
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls
+    assert all("neighbor_sweep/k1/" in line for line in calls)
+    assert "/colmap_build/" in text
+    assert all(f"/commit/{part}/" in text
+               for part in ("deaths", "compaction", "births"))
 
 
 def test_the_tpu_library_stays_out_of_the_test_process(described):
