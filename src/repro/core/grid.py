@@ -5,10 +5,10 @@ indexes boxes *row-major*; pointer chasing and per-box timestamps are CPU
 idioms. The TPU-native formulation (DESIGN.md §2–§3):
 
   build:  linear (row-major) box key per agent → parallel sort by key →
-          per-box (start, count) via one vectorized ``searchsorted`` over the
-          dense table of exactly ``prod(dims)`` boxes. O(#agents log #agents)
-          fully parallel work and O(#boxes) *vector* memset equivalents — no
-          serial O(#boxes) pass, which is what the paper's timestamp trick was
+          per-box (start, count) read from the sorted keys by one scatter
+          into the dense table of exactly ``prod(dims)`` boxes. O(#agents)
+          fully parallel work and O(#boxes) *vector* scans — no serial
+          O(#boxes) pass, which is what the paper's timestamp trick was
           avoiding (DESIGN.md §2).
   query:  because z is the fastest-varying key axis, the 3×3×3 stencil (paper
           §3.1) collapses into **9 contiguous runs of ≤3 boxes**: 9 range
@@ -144,10 +144,11 @@ _DEAD_KEY = morton.DEAD_KEY
 #
 # Two realizations, selected by ``impl``:
 #   * "xla" — an in-graph LSD radix cascade: each pass histograms one
-#     _DIGIT_BITS-wide digit per 1024-slot block (rank-within-digit via the
-#     block-sorted segment boundaries), exclusive-scans block histograms into
-#     global offsets, and applies the pass with ONE length-N scatter. Valid
-#     under jit, lax.cond, and shard_map; portable to accelerators.
+#     _DIGIT_BITS-wide digit per 1024-slot block (rank-within-digit and the
+#     histogram read from the block-sorted row's run boundaries, no search),
+#     exclusive-scans block histograms into global offsets, and applies the
+#     pass with ONE length-N scatter. Valid under jit, lax.cond, and
+#     shard_map; portable to accelerators.
 #   * "host" — jax.pure_callback into numpy's stable integer argsort (an LSD
 #     radix cascade on these dtypes, ~3.7× faster than jnp.argsort at 16M
 #     keys). OPT-IN ONLY: on jaxlib 0.4.37's CPU runtime, converting a
@@ -188,12 +189,14 @@ def _radix_pass(vals: jnp.ndarray, order: jnp.ndarray, shift: int
 
     vals/order are block-padded to a multiple of _SORT_BLOCK. Packing
     (digit << _LANE_BITS) | lane and value-sorting each block row yields the
-    per-block stable digit order without an argsort/take_along_axis pair;
-    rank-within-digit falls out of the sorted block's segment boundaries
-    (one searchsorted per block — the "segment cumsum" of the counting
-    sort), and the cross-block exclusive scan of the per-block histograms
-    turns local ranks into global destinations. The pass is applied with a
-    single length-N scatter of the inverse permutation.
+    per-block stable digit order without an argsort/take_along_axis pair.
+    Each digit is one run of the sorted row, so rank-within-digit is the
+    lane minus the run's first lane (a running max of the run starts), and
+    the per-block histogram is a scatter-add into the (block, digit) cells,
+    whose indices the sorted rows already give in ascending order. The
+    cross-block exclusive scan of the histograms turns local ranks into
+    global destinations, and the pass is applied with a single length-N
+    scatter of the inverse permutation.
     """
     n = vals.shape[0]
     nb = n // _SORT_BLOCK
@@ -204,16 +207,20 @@ def _radix_pass(vals: jnp.ndarray, order: jnp.ndarray, shift: int
     d_sorted = (packed >> _LANE_BITS).astype(jnp.int32)           # (nb, B)
     lane_src = (packed & jnp.uint32(_SORT_BLOCK - 1)).astype(jnp.int32)
 
-    ids = jnp.arange(d + 1, dtype=jnp.int32)
-    bounds = jax.vmap(lambda row: jnp.searchsorted(row, ids))(d_sorted)
-    counts_b = bounds[:, 1:] - bounds[:, :-1]                     # (nb, D)
+    rows = jnp.arange(nb, dtype=jnp.int32)[:, None]
+    j = jnp.arange(_SORT_BLOCK, dtype=jnp.int32)[None, :]
+    first = jnp.concatenate([jnp.ones((nb, 1), bool),
+                             d_sorted[:, 1:] != d_sorted[:, :-1]], axis=1)
+    local = j - jax.lax.cummax(jnp.where(first, j, 0), axis=1)  # rank in digit
+    # sorted indices: on the TPU an unsorted scatter into a table this large
+    # sorts its indices first, which costs time and megabytes of program
+    counts_b = jnp.zeros((nb * d,), jnp.int32).at[
+        (rows * d + d_sorted).reshape(-1)].add(
+        1, indices_are_sorted=True).reshape(nb, d)                # (nb, D)
     off_d = jnp.concatenate([jnp.zeros((1,), counts_b.dtype),
                              jnp.cumsum(counts_b.sum(axis=0))[:-1]])
     cross = jnp.cumsum(counts_b, axis=0) - counts_b               # excl. scan
 
-    rows = jnp.arange(nb, dtype=jnp.int32)[:, None]
-    j = jnp.arange(_SORT_BLOCK, dtype=jnp.int32)[None, :]
-    local = j - bounds[rows, d_sorted]                            # rank in digit
     dst = (off_d[d_sorted] + cross[rows, d_sorted] + local).reshape(-1)
     src = (rows * _SORT_BLOCK + lane_src).reshape(-1)
     inv = jnp.zeros((n,), jnp.int32).at[dst].set(
@@ -498,17 +505,23 @@ def box_tables(sorted_keys: jnp.ndarray, table_size: int
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense per-box (starts, counts) from the key-sorted keys.
 
-    One searchsorted over M+1 ids gives starts AND counts (ends[i]=starts[i+1];
-    the M'th entry lands at n_live because dead keys sort above every box id).
-    Shared with the kernel compat wrapper (kernels/ops.collision_force) so the
-    table derivation exists exactly once. Counts use the capacity-
-    parameterized :func:`table_count_dtype`.
+    Read from the runs of the sorted keys: each box's end (its last position
+    + 1) is a scatter-max of the positions into their boxes, whose indices
+    are the sorted keys themselves (dead keys, which sort above every box
+    id, are dropped), and a running max gives every empty box the end of the
+    box before it. starts[i] = ends[i-1] — equal to
+    ``searchsorted(sorted_keys, arange(M), "left")`` — and counts are
+    ends - starts. Shared with the kernel compat wrapper
+    (kernels/ops.collision_force) so the table derivation exists exactly
+    once. Counts use the capacity-parameterized :func:`table_count_dtype`.
     """
-    box_ids = jnp.arange(table_size + 1, dtype=jnp.uint32)
-    bounds = jnp.searchsorted(sorted_keys, box_ids, side="left").astype(jnp.int32)
-    counts = (bounds[1:] - bounds[:-1]).astype(
-        table_count_dtype(sorted_keys.shape[0]))
-    return bounds[:-1], counts
+    c = sorted_keys.shape[0]
+    pos = jnp.arange(c, dtype=jnp.int32)
+    box = jnp.minimum(sorted_keys, jnp.uint32(table_size)).astype(jnp.int32)
+    ends = jax.lax.cummax(jnp.zeros((table_size,), jnp.int32).at[box].max(
+        pos + 1, indices_are_sorted=True, mode="drop"))
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    return starts, (ends - starts).astype(table_count_dtype(c))
 
 
 def _index_tables(spec: GridSpec, sorted_keys: jnp.ndarray):

@@ -49,6 +49,9 @@ from repro.core.behaviors import GrowDivide
 # ---------------------------------------------------------------------------
 
 TABLE = 9 * 9 * 9               # non-power-of-two linear key domain
+# one, two and three radix passes (11-bit digits): 729 boxes, the oncology
+# cell's ~117³ and the epidemiology cell's 170³
+TABLES = (TABLE, 117 ** 3, 170 ** 3)
 _DEAD = np.uint32(0xFFFFFFFF)   # morton.DEAD_KEY
 
 
@@ -56,12 +59,12 @@ def _oracle(keys):
     return np.argsort(keys, kind="stable").astype(np.int32)
 
 
-def _key_cases(rng, c):
-    uniform = rng.integers(0, TABLE, c).astype(np.uint32)
+def _key_cases(rng, c, table=TABLE):
+    uniform = rng.integers(0, table, c).astype(np.uint32)
     mixed = uniform.copy()
     mixed[rng.random(c) < 0.3] = _DEAD
     clustered = rng.choice(
-        np.asarray([0, 5, TABLE - 1], np.uint32), c).astype(np.uint32)
+        np.asarray([0, 5, table - 1], np.uint32), c).astype(np.uint32)
     return {"uniform": uniform,
             "uniform_with_dead": mixed,
             "clustered": clustered,
@@ -69,15 +72,49 @@ def _key_cases(rng, c):
             "single_box": np.zeros(c, np.uint32)}
 
 
+@pytest.mark.parametrize("table", TABLES)
 @pytest.mark.parametrize("impl", ["host", "xla", "auto", "argsort"])
-def test_counting_sort_bit_exact(rng, impl):
-    # sizes below / far below / at / just past the radix block (1024)
-    for c in (1, 7, 1024, 1359):
-        for name, keys in _key_cases(rng, c).items():
+def test_counting_sort_bit_exact(rng, impl, table):
+    # sizes below / far below / at / just past the radix block (1024), and
+    # many blocks
+    for c in (1, 7, 1024, 1359, 70_000):
+        for name, keys in _key_cases(rng, c, table).items():
             order = np.asarray(G.counting_sort_order(
-                jnp.asarray(keys), TABLE, impl=impl))
+                jnp.asarray(keys), table, impl=impl))
             assert order.dtype == np.int32, (impl, name, c)
             assert np.array_equal(order, _oracle(keys)), (impl, name, c)
+    # under vmap (the ensemble's lane axis): two lanes, each its own keys
+    cases = _key_cases(rng, 1359, table)
+    lanes = np.stack([cases["uniform_with_dead"], cases["clustered"]])
+    order = np.asarray(jax.vmap(lambda k: G.counting_sort_order(
+        k, table, impl=impl))(jnp.asarray(lanes)))
+    assert np.array_equal(order, np.stack([_oracle(k) for k in lanes])), impl
+
+
+def _box_cases(rng):
+    """(sorted keys, table size) with the layouts box_tables must read."""
+    m = 17 ** 3
+    uniform = rng.integers(0, m, 5000).astype(np.uint32)
+    uniform[-1200:] = _DEAD
+    inner = rng.integers(1, m - 1, 3000).astype(np.uint32)
+    return {"uniform_dead_tail": (np.sort(uniform), m),
+            "first_and_last_empty": (np.sort(inner), m),
+            "all_dead": (np.full(2048, _DEAD, np.uint32), m),
+            "one_box": (np.asarray([0] * 40 + [_DEAD] * 9, np.uint32), 1),
+            "int32_counts": (np.sort(rng.integers(0, 729, 1 << 15)
+                                     .astype(np.uint32)), 729)}
+
+
+@pytest.mark.parametrize("case", ["uniform_dead_tail", "first_and_last_empty",
+                                  "all_dead", "one_box", "int32_counts"])
+def test_box_tables_match_searchsorted(rng, case):
+    keys, m = _box_cases(rng)[case]
+    starts, counts = G.box_tables(jnp.asarray(keys), m)
+    bounds = np.searchsorted(keys, np.arange(m + 1, dtype=np.uint32), "left")
+    assert starts.dtype == np.int32
+    assert counts.dtype == (np.int32 if len(keys) >= 1 << 15 else np.int16)
+    assert np.array_equal(np.asarray(starts), bounds[:-1])
+    assert np.array_equal(np.asarray(counts), np.diff(bounds))
 
 
 def test_counting_sort_rejects_unknown_impl():

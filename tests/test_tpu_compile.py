@@ -18,6 +18,7 @@ that ran nothing.
 """
 
 import contextlib
+import functools
 import itertools
 import multiprocessing
 import re
@@ -74,11 +75,13 @@ def _k1(capacity, adhesion, maxb=MAXB):
             compiled.memory_analysis().output_size_in_bytes)
 
 
+@functools.lru_cache(maxsize=None)
 def _sir_step_text(scopes=True, counters=True, n=4096) -> str:
     """The SIR step at ``n`` agents compiled for the described chip, with
     or without the engine's phase scopes and the sweep's work counters; at
     1,048,576 agents it is the epidemiology-sir cell's step (bench/configs:
-    a cube of side n^(1/3)·5 µm, query blocks of 4,096)."""
+    a cube of side n^(1/3)·5 µm, query blocks of 4,096). Kept per process:
+    several tests read the same step."""
     import jax
     import jax.numpy as jnp
     from repro.core import EngineConfig, Simulation, grid
@@ -110,6 +113,7 @@ def _sir_step_text(scopes=True, counters=True, n=4096) -> str:
         return sim._step_fn.lower(state).compile().as_text()
 
 
+@functools.lru_cache(maxsize=None)
 def _oncology_step_text(agents=1_048_576) -> str:
     """The oncology-growth cell's step (bench/configs/oncology.json: K1
     forces at the pinned column-map width, growth, division, death) at
@@ -258,18 +262,46 @@ def test_the_cells_window_path_reads_no_gather_on_v5e(described):
     assert " gather(" in loops["gather_path"]
 
 
+def _unlabelled(text: str) -> list:
+    """Scatter, sort and gather ops of the compiled step that lost their
+    ``op_name``, so their time would read as unscoped."""
+    from bench import trace
+    labels = trace.hlo_labels(text)
+    return [name for name, kinds in trace.hlo_kinds(text).items()
+            if kinds & {"scatter", "sort", "gather"} and name not in labels]
+
+
+_CELL_STEPS = {"epidemiology-sir": functools.partial(_sir_step_text,
+                                                     n=1_048_576),
+               "oncology-growth": _oncology_step_text}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_STEPS))
+def test_the_cells_grid_build_runs_no_loop_on_v5e(described, cell):
+    """The grid build reads its tables from the sorted keys' run
+    boundaries: in both cells' steps, compiled for the described chip, no
+    while loop lies under ``grid_build`` (a binary search lowers to one, a
+    chain of dependent gathers), and the build's scatters, like every
+    scatter, sort and gather, keep their ``op_name``. Their indices come
+    sorted, so the compiler sorts none of them first: such a sort costs
+    time and megabytes of program, which the device's peak memory counts."""
+    text = described.submit(_CELL_STEPS[cell]).result()
+    loops = re.findall(r" while\(.*op_name=\"([^\"]*grid_build/[^\"]*)\"",
+                       text)
+    assert not loops, loops
+    assert not _unlabelled(text)
+    assert re.search(r" scatter\(.*op_name=\"[^\"]*grid_build/", text)
+    assert not re.search(r" sort\(.*op_name=\"[^\"]*grid_build/scatter", text)
+
+
 def test_the_oncology_cells_step_compiles_for_v5e(described):
     """The oncology-growth cell's step compiles for the described chip at
     its capacity, 2,097,152 slots, with K1 at the pinned column-map width:
     its launches sit in the ``k1`` scope, inside the sweep's, and no
     scatter, sort or gather has lost its ``op_name`` (the column map's
     per-row-block scatter once did, and its time read as unscoped)."""
-    from bench import trace
     text = described.submit(_oncology_step_text).result()
-    labels = trace.hlo_labels(text)
-    assert not [name for name, kinds in trace.hlo_kinds(text).items()
-                if kinds & {"scatter", "sort", "gather"}
-                and name not in labels]
+    assert not _unlabelled(text)
     calls = [line for line in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert calls
