@@ -34,7 +34,7 @@ SIDE = max(30.0, (N_AGENTS ** (1 / 3)) * 4.2)
 
 def make_service() -> SimService:
     # sweep regime: comparison sort — the counting sort's scatter passes
-    # batch poorly under the lane axis on XLA:CPU (benchmarks/ensemble.py)
+    # batch poorly under the lane axis on XLA:CPU (ROADMAP D2)
     cfg = EngineConfig(capacity=-(-N_AGENTS // 64) * 64,
                        domain_lo=(0, 0, 0), domain_hi=(SIDE,) * 3,
                        interaction_radius=3.0, use_forces=False,

@@ -6,14 +6,11 @@ Reproduction (and TPU adaptation) of:
 
 Package layout:
   repro.core      -- the paper's engine (grid neighbor search, Morton sort,
-                     parallel add/remove, static-region detection, forces)
-  repro.kernels   -- Pallas TPU kernels for perf-critical hot spots
-  repro.models    -- LM substrate for the assigned architecture pool
-  repro.configs   -- architecture configs (10 assigned + ABM-native)
-  repro.train     -- optimizer / train_step / checkpointing
-  repro.serve     -- paged KV cache + decode + continuous batching
-  repro.launch    -- mesh, multi-pod dry-run, drivers
-  repro.roofline  -- roofline analysis from compiled HLO
+                     parallel add/remove, static-region detection, forces),
+                     the distributed and ensemble engines, and checkpointing
+  repro.kernels   -- the Pallas K1 contact-force kernel and its oracle
+  repro.serve     -- SimService: continuous batching over ensemble lanes
+  repro.launch    -- the simulate and sim_serve CLIs, the compile cache
 """
 
 __version__ = "1.0.0"

@@ -1,13 +1,13 @@
-"""Checkpoint / restore — fault tolerance for long runs (DESIGN.md §7).
+"""Checkpoint / restore of pytrees — the writer under simcheck.py (DESIGN.md §7.5).
 
-Design goals for 1000+ node runs:
+Design goals for long runs:
   * **Atomic**: write to a tmp dir, fsync, rename — a preempted write never
     corrupts the latest checkpoint.
   * **Async**: ``save_async`` snapshots device arrays to host (cheap) and
-    writes on a background thread — training continues immediately.
+    writes on a background thread — the simulation continues immediately.
   * **Elastic**: restore() only needs the *tree*; arrays are ``device_put``
     with whatever sharding the *current* mesh prescribes, so a run checkpointed
-    on 512 chips restarts on 256 (or 1 CPU) unchanged.
+    on four devices restarts on fewer (or one CPU) unchanged.
   * **Self-describing**: a manifest (step, tree structure, shapes/dtypes)
     travels with the data; restore validates structural compatibility.
 

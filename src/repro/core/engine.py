@@ -1199,7 +1199,7 @@ class CapacityLadder(LadderDriverBase):
                         flags too and ``max_per_run`` clears
 
     Growth events are recorded in ``self.rungs`` and recompiles counted in
-    ``self.recompiles`` (benchmarks/capacity.py reports both).
+    ``self.recompiles``.
     """
 
     def __init__(self, config: EngineConfig, behaviors: Sequence[Behavior] = (),

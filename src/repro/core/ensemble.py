@@ -20,10 +20,9 @@ program advances every member per step (DESIGN.md §8):
     still ride through the vmapped compute (dense batched math has no
     data-dependent skip), but every write is frozen via ``jnp.where`` and
     their stats are zeroed — a retired lane holds its final state bit-for-bit
-    until the service overwrites it, exactly like an idle slot in
-    ``serve/batching.py`` holds its KV rows. The economics are the same as
-    continuous batching: an idle lane costs its batch slot, so the service's
-    job is to keep lanes full, not to make idle lanes free.
+    until the service overwrites it. The economics are those of continuous
+    batching: an idle lane costs its batch slot, so the service's job is to
+    keep lanes full, not to make idle lanes free.
 
   * **Shared-rung ladder.** Capacity knobs (pool capacity, run width,
     pair-list width) stay *shared* across lanes — one rung, one compiled

@@ -1,8 +1,8 @@
 """Simulation checkpoint/resume and the supervised run loop (DESIGN.md §7.5).
 
-The train side already had fault tolerance (train/checkpoint.py: atomic
-tmp-then-rename saves, async writer, GC); this module puts the *simulation*
-run state through the same writer and builds the recovery logic on top:
+The checkpoint writer (checkpoint.py: atomic tmp-then-rename saves, async
+writer, GC) stores pytrees; this module puts the *simulation* run state
+through it and builds the recovery logic on top:
 
   * ``save_state`` / ``restore_state`` — the complete single-device run
     state (pool SoA channels, capacity-stable RNG key, RebuildPolicy cache,
@@ -43,8 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..train import checkpoint as ckpt_mod
-from . import compaction, grid as grid_mod
+from . import checkpoint as ckpt_mod, compaction, grid as grid_mod
 from .behaviors import Behavior
 from .distributed import (DistConfig, DistState, DistributedCapacityLadder,
                           DistributedSimulation, OWNED, partition_global,

@@ -85,8 +85,9 @@ def update_static_flags(pool: AgentPool, spec: GridSpec, grid: GridState,
     superset of the radius test, see module docstring); condition iv from the
     per-agent ``force_nnz`` bookkeeping. Cost is one scatter-add over the box
     table plus three windowed ORs — static detection no longer costs a second
-    neighbor sweep, which is what makes ``detect_static=True`` a measured win
-    instead of pure overhead (BENCH_statics.json).
+    neighbor sweep, which is what makes ``detect_static=True`` a win instead
+    of pure overhead on a quiescent population (a one-core CPU measurement;
+    no cell runs static detection on the chip yet).
     """
     nbh = neighborhood_disturbed(spec, grid, pool, iteration)
     box = jnp.minimum(grid.keys, jnp.uint32(spec.table_size - 1)).astype(jnp.int32)

@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""repro.kernels — the Pallas K1 contact-force kernel (collision_force.py),
+its jit'd wrappers (ops.py) and its dense oracle (ref.py)."""

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 
 from ..core import grid, morton
 from . import collision_force as k1
-from . import flash_attention as k2
 
 BLOCK = k1.BLOCK
 
@@ -375,28 +374,3 @@ def collision_force(position: jnp.ndarray, diameter: jnp.ndarray,
     force = jnp.zeros((c, 3), jnp.float32).at[order].set(f_sorted)
     nnz = jnp.zeros((c,), jnp.int32).at[order].set(nnz_sorted)
     return force, nnz, work.overflow
-
-
-# ---------------------------------------------------------------------------
-# K2: flash attention
-# ---------------------------------------------------------------------------
-
-def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    *, causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
-    """Padding-safe wrapper: pads Sq/Sk to block multiples, masks, unpads."""
-    b, hq, sq, d = q.shape
-    sk = k.shape[2]
-    block_q = min(block_q, max(16, 1 << (sq - 1).bit_length() if sq > 1 else 16))
-    block_k = min(block_k, max(16, 1 << (sk - 1).bit_length() if sk > 1 else 16))
-    sq_pad = ((sq + block_q - 1) // block_q) * block_q
-    sk_pad = ((sk + block_k - 1) // block_k) * block_k
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, sq_pad - sq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, sk_pad - sk), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, sk_pad - sk), (0, 0)))
-    out = k2.flash_attention_kernel(qp, kp, vp, causal=causal, scale=scale,
-                                    block_q=block_q, block_k=block_k,
-                                    sk_actual=sk, kv_offset=sk - sq,
-                                    interpret=interpret)
-    return out[:, :, :sq, :]

@@ -2,7 +2,6 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 
@@ -41,28 +40,3 @@ def collision_force_ref(position: jnp.ndarray, diameter: jnp.ndarray,
     nnz = jnp.sum(jnp.sum(pair * pair, -1) > (1e-7) ** 2, axis=1).astype(jnp.int32)
     return force, nnz
 
-
-def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        causal: bool = True, scale: float | None = None
-                        ) -> jnp.ndarray:
-    """Reference softmax attention with GQA broadcast.
-
-    q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D) with Hq % Hkv == 0.
-    """
-    b, hq, sq, dh = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
-    group = hq // hkv
-    kk = jnp.repeat(k, group, axis=1)
-    vv = jnp.repeat(v, group, axis=1)
-    scale = scale if scale is not None else 1.0 / (dh ** 0.5)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * scale
-    if causal:
-        # supports Sq == Sk (training/prefill) and Sq < Sk (chunked) with the
-        # query block aligned to the *end* of the key sequence
-        qpos = jnp.arange(sq) + (sk - sq)
-        kpos = jnp.arange(sk)
-        mask = kpos[None, :] <= qpos[:, None]
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vv.astype(jnp.float32)).astype(q.dtype)

@@ -1,8 +1,7 @@
 """Persistent XLA compilation cache, placeable from outside the program.
 
 Every entry point (``launch/simulate.py``, ``launch/sim_serve.py``,
-``benchmarks/run.py``, ``chip_smoke.py``) calls :func:`enable` before its
-first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+``chip_smoke.py``) calls :func:`enable` before its first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
 it and nothing here overrides it. Otherwise the cache lives at a fixed
 directory of the checkout, ``.jax_cache/`` next to ``src/``: the directory
 is part of the cache key, so it is derived from this file's location and

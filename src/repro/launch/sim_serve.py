@@ -29,7 +29,7 @@ from . import compile_cache
 
 def make_service(n_lanes: int, agents: int, side: float) -> SimService:
     # sweep regime: comparison sort — the counting sort's scatter passes
-    # batch poorly under the lane axis on XLA:CPU (benchmarks/ensemble.py)
+    # batch poorly under the lane axis on XLA:CPU (ROADMAP D2)
     cfg = EngineConfig(
         capacity=-(-agents // 64) * 64,
         domain_lo=(0.0,) * 3, domain_hi=(side,) * 3,

@@ -1,5 +1,2 @@
-"""repro.serve — paged KV pool (paper §4.3) + continuous batching (paper §3.2)."""
-from .kv_cache import (PagedCacheSpec, PagedCacheState, admit_sequence,
-                       append_token, gather_kv, init_cache, release_sequence)
-from .batching import ContinuousBatcher, Request
+"""repro.serve — the simulation service: continuous batching over ensemble lanes."""
 from .sim_service import FinishedSim, SimRequest, SimService

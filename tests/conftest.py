@@ -1,5 +1,6 @@
-"""Shared test fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
-must see the single real CPU device; only launch/dryrun.py forces 512."""
+"""Shared test fixtures. NOTE: no XLA_FLAGS here — the test process must see
+the single real CPU device; tests that need more shards force host devices
+in a subprocess of their own."""
 
 import numpy as np
 import pytest

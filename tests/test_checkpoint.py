@@ -1,21 +1,53 @@
-"""train/checkpoint.py corner cases — the fault-tolerance substrate.
+"""core/checkpoint.py — the fault-tolerance substrate.
 
-The happy paths (roundtrip, async+gc, mismatch raises) live in
-test_train_serve.py; the simulation checkpointing layer (core/simcheck.py)
-leans on the corners tested here: the GC keep-window under interleaved
-sync/async saves, crash debris (a stale ``step_N.tmp`` dir from a SIGKILLed
-write) never corrupting later saves or discovery, the structure-mismatch
-message naming the offending keys, and manifest ``extras`` round-tripping.
+The happy paths (roundtrip, async+gc, mismatch raises), and the corners the
+simulation checkpointing layer (core/simcheck.py) leans on: the GC
+keep-window under interleaved sync/async saves, crash debris (a stale
+``step_N.tmp`` dir from a SIGKILLed write) never corrupting later saves or
+discovery, the structure-mismatch message naming the offending keys, and
+manifest ``extras`` round-tripping.
 """
 
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.train import checkpoint
+from repro.core import checkpoint
+
+
+def test_checkpoint_roundtrip(tmp_path):
+    tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+            "b": {"c": jnp.asarray([1, 2], jnp.int32),
+                  "d": jnp.asarray(3.5, jnp.bfloat16)}}
+    checkpoint.save(str(tmp_path), 7, tree)
+    assert checkpoint.latest_step(str(tmp_path)) == 7
+    like = jax.tree.map(lambda x: jnp.zeros_like(x), tree)
+    out = checkpoint.restore(str(tmp_path), 7, like)
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_checkpoint_async_and_gc(tmp_path):
+    ck = checkpoint.AsyncCheckpointer(str(tmp_path), keep=2)
+    tree = {"w": jnp.ones((4,))}
+    for s in (1, 2, 3, 4):
+        ck.save_async(s, tree)
+    ck.wait()
+    steps = checkpoint.list_steps(str(tmp_path))
+    assert steps == [3, 4]              # older checkpoints gc'd
+    assert checkpoint.latest_step(str(tmp_path)) == 4
+
+
+def test_checkpoint_structure_mismatch_raises(tmp_path):
+    checkpoint.save(str(tmp_path), 1, {"a": jnp.ones(3)})
+    with pytest.raises(ValueError):
+        checkpoint.restore(str(tmp_path), 1, {"zzz": jnp.ones(3)})
+
 
 
 def test_gc_keep_window_exact(tmp_path):

@@ -232,7 +232,7 @@ def test_restore_adapts_env_across_rebuild_modes(tmp_path):
 
 
 def test_restore_rejects_non_sim_checkpoint(tmp_path):
-    from repro.train import checkpoint
+    from repro.core import checkpoint
     checkpoint.save(str(tmp_path), 1, {"a": jnp.ones(3)})
     with pytest.raises(ValueError, match="not a simulation checkpoint"):
         restore_state(str(tmp_path), _cfg(), [])
@@ -326,7 +326,7 @@ def test_supervisor_capacity_exhaustion_emergency_checkpoint(tmp_path):
     assert any(iv["kind"] == "capacity_exhausted"
                for iv in rep.interventions)
     # the emergency checkpoint preserved the last-good trajectory on disk
-    from repro.train import checkpoint
+    from repro.core import checkpoint
     assert checkpoint.latest_step(str(tmp_path)) is not None
 
 
@@ -409,7 +409,7 @@ def test_sigkill_ladder_run_resumes_bit_exact(tmp_path):
     assert killed.returncode == -signal.SIGKILL, \
         f"child exited {killed.returncode}: {killed.stderr[-2000:]}"
     assert "RESULT survived" not in killed.stdout
-    from repro.train import checkpoint
+    from repro.core import checkpoint
     assert checkpoint.latest_step(ckpt) is not None, \
         "no checkpoint survived the kill"
     resumed = _result_line(_run_child(["resume", ckpt]))

@@ -103,53 +103,3 @@ def test_collision_force_multi_launch_bit_exact(rng, monkeypatch):
     np.testing.assert_array_equal(np.asarray(many[0]), np.asarray(one[0]))
     np.testing.assert_array_equal(np.asarray(many[1]), np.asarray(one[1]))
     assert int(np.asarray(one[1]).sum()) > 0
-
-
-# ---------------------------------------------------------------------------
-# K2: flash attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,hq,hkv,sq,sk,d,causal,dtype", [
-    (2, 4, 2, 128, 128, 64, True, jnp.float32),
-    (1, 8, 8, 256, 256, 32, True, jnp.float32),
-    (1, 4, 1, 100, 100, 64, True, jnp.float32),     # non-aligned seq
-    (2, 2, 2, 64, 192, 32, True, jnp.float32),      # chunked decode (Sq < Sk)
-    (1, 4, 2, 128, 128, 64, False, jnp.float32),    # non-causal (encoder)
-    (1, 2, 2, 128, 128, 128, True, jnp.bfloat16),   # bf16 inputs
-    (1, 2, 1, 384, 384, 64, True, jnp.float32),     # multi-block both axes
-])
-def test_flash_attention_matches_ref(rng, b, hq, hkv, sq, sk, d, causal, dtype):
-    q = jnp.asarray(rng.standard_normal((b, hq, sq, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((b, hkv, sk, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, hkv, sk, d)), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal)
-    expect = ref.flash_attention_ref(q, k, v, causal=causal)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expect, np.float32), atol=tol)
-
-
-@settings(max_examples=8, deadline=None)
-@given(st.integers(1, 2), st.sampled_from([1, 2, 4]), st.integers(1, 3),
-       st.sampled_from([32, 64]), st.integers(0, 10_000))
-def test_flash_attention_property(b, group, hkv, d, seed):
-    rng = np.random.default_rng(seed)
-    sq = int(rng.integers(2, 200))
-    hq = group * hkv
-    q = jnp.asarray(rng.standard_normal((b, hq, sq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, hkv, sq, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, hkv, sq, d)), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=True)
-    expect = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=3e-5)
-
-
-def test_flash_attention_rows_sum_to_one_property(rng):
-    """softmax sanity: attending to identical V returns V."""
-    b, h, s, d = 1, 2, 130, 32
-    q = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
-    v = jnp.broadcast_to(jnp.asarray(rng.standard_normal((1, 1, 1, d)),
-                                     jnp.float32), (b, h, s, d))
-    out = ops.flash_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(v), atol=1e-5)

@@ -1,8 +1,7 @@
 """SimService (DESIGN.md §8): continuous batching over ensemble lanes.
 
-The serving corner cases the batching loop must get right, mirroring the
-token-serving batcher's contract (serve/batching.py): admission into a full
-pool queues (never drops), retirement frees the lane at iteration
+The serving corner cases the batching loop must get right: admission into
+a full pool queues (never drops), retirement frees the lane at iteration
 granularity and the next request reuses it with a fresh RNG stream, an
 all-idle service never launches the jitted step, and a checkpoint taken
 mid-churn resumes bit-exact.

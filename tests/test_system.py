@@ -1,23 +1,14 @@
-"""End-to-end behaviour tests for the paper's system.
+"""End-to-end behaviour test for the paper's system.
 
-The headline claims, executed small: (1) a full simulation with every paper
-optimization enabled runs, conserves invariants, and skips static work;
-(2) fault tolerance round-trips a training run through a checkpoint with
-identical results (bitwise resume)."""
+The headline claim, executed small: a full simulation with every paper
+optimization enabled runs, conserves invariants, and skips static work.
+(Checkpoint/resume bit-exactness: tests/test_fault_tolerance.py and
+tests/test_launch.py.)"""
 
-import dataclasses
-import os
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCHS
 from repro.core import EngineConfig, ForceParams, Simulation
 from repro.core.behaviors import GrowDivide, RandomDeath
-from repro.data import DataConfig, batch_at
-from repro.models import build_model, reduced_config
-from repro.train import AdamWConfig, checkpoint, init_state, make_train_step
 
 
 def test_full_engine_all_optimizations(rng):
@@ -40,32 +31,3 @@ def test_full_engine_all_optimizations(rng):
     assert not np.isnan(np.asarray(st.pool.position)).any()
     assert int(st.stats["n_active"]) <= n                # statics never exceed
 
-
-def test_train_checkpoint_resume_bitwise(tmp_path):
-    """Kill-and-resume yields the same parameters as an uninterrupted run."""
-    arch = reduced_config(ARCHS["qwen2-1.5b"])
-    model = build_model(arch)
-    ocfg = AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=10)
-    dcfg = DataConfig(vocab_size=arch.vocab_size, seq_len=32, global_batch=4)
-    step = jax.jit(make_train_step(model, ocfg))
-
-    params = model.init_params(jax.random.PRNGKey(0))
-    opt = init_state(ocfg, params)
-    # uninterrupted: 6 steps
-    p_ref, o_ref = params, opt
-    for s in range(6):
-        p_ref, o_ref, _ = step(p_ref, o_ref, batch_at(dcfg, s))
-
-    # interrupted at step 3 + resume (stateless-by-step data pipeline)
-    p, o = params, opt
-    for s in range(3):
-        p, o, _ = step(p, o, batch_at(dcfg, s))
-    checkpoint.save(str(tmp_path), 3, {"params": p, "opt": o})
-    restored = checkpoint.restore(str(tmp_path), 3, {"params": p, "opt": o})
-    p, o = restored["params"], restored["opt"]
-    for s in range(3, 6):
-        p, o, _ = step(p, o, batch_at(dcfg, s))
-
-    for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
